@@ -47,15 +47,16 @@ def run() -> None:
     # lattice digest: the accelerator-placed integrity kernel must be
     # BIT-EXACT against the jnp oracle (uint32 wraparound arithmetic is
     # deterministic on both paths — any mismatch is a kernel bug, not
-    # float noise), since the oracle IS the CPU production digest path
-    from repro.core.integrity import DIGEST_BLOCK, DIGEST_TILE
+    # float noise), since the oracle is the digest off the TPU
+    from repro.core.integrity import DIGEST_BLOCK
     from repro.kernels.digest import block_digest, digest_ref
-    nb = 64 * DIGEST_TILE
+    tile = 8
+    nb = 64 * tile
     panels = jnp.asarray(
         np.random.default_rng(0).integers(0, 1 << 32, (nb, DIGEST_BLOCK),
                                           dtype=np.uint32))
     t, d = time_it(lambda: jax.block_until_ready(
-        block_digest(panels, tile=DIGEST_TILE, interpret=True)))
+        block_digest(panels, tile=tile, interpret=True)))
     d_ref = np.asarray(digest_ref(panels))
     exact = bool((np.asarray(d) == d_ref).all())
     emit("kernel/digest_interp", t * 1e6,

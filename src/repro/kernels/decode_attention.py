@@ -7,6 +7,9 @@ state (m, l, acc) persists in VMEM scratch across the sequential k-block
 axis.  Ring caches and partial fills are handled by an explicit
 ``k_pos`` operand (absolute position per slot, -1 = empty) and the query
 position ``q_pos`` — identical semantics to the model's cache masks.
+``q_pos`` sits whole in SMEM (one scalar per sequence, read at the grid's
+batch index); ``k_pos`` is viewed as (B, 1, S) so its (1, bk) block is
+lane-dense for any batch size.
 
 VMEM per step (G<=16, bk=512, hd<=256): k/v blocks 2*512*256*2B = 512 KiB,
 scores G*512*4B <= 32 KiB — small; the kernel is HBM-bandwidth-bound by
@@ -20,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -27,6 +31,7 @@ NEG_INF = -1e30
 def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
                    acc_ref, m_ref, l_ref, *, G: int, bk: int, nk: int,
                    scale: float, window: int):
+    b = pl.program_id(0)
     ik = pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -40,20 +45,20 @@ def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     v = v_ref[0, 0].astype(jnp.float32)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (G, bk)
 
-    k_pos = kpos_ref[0]                                    # (bk,) i32
-    q_pos = qpos_ref[0]                                    # scalar i32
+    k_pos = kpos_ref[0]                                    # (1, bk) i32
+    q_pos = qpos_ref[b]                                    # scalar i32
     keep = jnp.logical_and(k_pos >= 0, k_pos <= q_pos)
     if window > 0:
         keep = jnp.logical_and(keep, k_pos > q_pos - window)
-    keep = jnp.broadcast_to(keep[None, :], (G, bk))
+    keep = jnp.broadcast_to(keep, (G, bk))
     s = jnp.where(keep, s, NEG_INF)
 
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev, l_prev = m_ref[...], l_ref[...]                # (G, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(keep, jnp.exp(s - m_new[:, None]), 0.0)
-    l_new = alpha * l_prev + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+    l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())))
     m_ref[...] = m_new
     l_ref[...] = l_new
@@ -62,7 +67,7 @@ def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
     def _finish():
         l = l_ref[...]
         safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bk", "interpret"))
@@ -84,24 +89,23 @@ def decode_attention_bhd(q: jax.Array, k: jax.Array, v: jax.Array,
 
     kernel = functools.partial(_decode_kernel, G=G, bk=bk, nk=nk,
                                scale=scale, window=window)
-    from jax.experimental.pallas import tpu as pltpu
     out = pl.pallas_call(
         kernel,
         grid=(B, Hkv, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, bk, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, j: (b, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_pos, qg, k, v, k_pos)
+    )(q_pos, qg, k, v, k_pos.reshape(B, 1, S))
     return out.reshape(B, Hq, hd)

@@ -8,8 +8,9 @@ index maps (q-head h reads kv-head h // G) — no materialized repeat.
 
 Supports causal and sliding-window masking via absolute block positions.
 The pure-jnp oracle is :func:`repro.kernels.ref.attention_ref` (which the
-model's `_attn_core` also uses); tests sweep shapes/dtypes in
-``interpret=True`` mode (this container is CPU-only; TPU is the target).
+model's `_attn_core` also uses); tests sweep shapes/dtypes in interpret
+mode on the CPU, and ``tests/test_tpu_compile.py`` compiles the kernel
+for a TPU v5e at SmolLM-360M widths.
 
 VMEM budget per grid step (defaults bq=bk=256, hd<=256, f32 scratch):
 q/k/v blocks 3*256*256*2B = 384 KiB, scores 256*256*4B = 256 KiB,

@@ -1,10 +1,11 @@
-"""Jitted public wrappers for the Pallas kernels.
+"""Jitted public wrappers for the Pallas kernels — the one backend seam.
 
-Model code calls these through ``ShardCtx.impl == "pallas"``; on this
-CPU-only container they execute in interpret mode (kernel bodies run as
-Python over numpy — TPU is the compile target, correctness is what's
-validated here).  Layout conversions between the model's (B, S, H, hd)
-convention and the kernels' (B, H, S, hd) happen here.
+Model code calls these through ``ShardCtx.impl == "pallas"`` and the
+integrity layer calls :func:`block_digest`.  :func:`on_tpu` alone decides
+how a kernel runs: compiled by Mosaic on a TPU backend, in interpret mode
+(kernel bodies run as Python over numpy, for correctness) on any other.
+Layout conversions between the model's (B, S, H, hd) convention and the
+kernels' (B, H, S, hd) happen here.
 """
 
 from __future__ import annotations
@@ -13,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from .decode_attention import decode_attention_bhd
+from .digest import block_digest as _block_digest
 from .flash_attention import flash_attention_bhsd
 from .quantize import dequantize_int8, quantize_int8
 from .ssd_scan import ssd_scan_bhsd
@@ -39,7 +41,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      k_pos: jax.Array, q_pos: jax.Array, *,
                      window: int = 0) -> jax.Array:
     """q: (B, 1, H, hd); k/v: (B, S, Hkv, hd) caches -> (B, 1, H, hd)."""
-    qt = q[:, 0].swapaxes(0, 0)                 # (B, H, hd)
     qt = jnp.swapaxes(q, 1, 2)[:, :, 0]         # (B, H, hd)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -66,3 +67,19 @@ def quantize(x: jax.Array, *, block: int = 256):
 
 def dequantize(q: jax.Array, s: jax.Array, shape: tuple[int, ...]):
     return dequantize_int8(q, s, shape, interpret=not on_tpu())
+
+
+#: panel rows per digest grid step (one sublane tile)
+DIGEST_TILE = 8
+
+
+def block_digest(panels: jax.Array) -> jax.Array:
+    """uint32 panels (nb, block) -> one lattice digest per block row; rows
+    zero-pad up to a multiple of ``DIGEST_TILE`` and the pad's digests
+    drop."""
+    nb = panels.shape[0]
+    pad = (-nb) % DIGEST_TILE
+    if pad:
+        panels = jnp.pad(jnp.asarray(panels), ((0, pad), (0, 0)))
+    return _block_digest(panels, tile=DIGEST_TILE,
+                         interpret=not on_tpu())[:nb]

@@ -3,7 +3,9 @@
 The compute half of the compressed gradient collective
 (parallel/collectives.compressed_psum): symmetric per-block int8 with f32
 scales.  Tiled so each grid step quantizes a (tile, block) panel from
-VMEM; the oracle is optim/compression.quantize_int8_blockwise.
+VMEM; the oracle is optim/compression.quantize_int8_blockwise.  Scales
+move as an (nb, 1) column inside the kernels, so each (tile, 1) block
+keeps the TPU's 2-D tiling; the public API keeps them flat (nb,).
 """
 
 from __future__ import annotations
@@ -17,16 +19,16 @@ from jax.experimental import pallas as pl
 
 def _quant_kernel(x_ref, q_ref, s_ref):
     x = x_ref[...].astype(jnp.float32)            # (tile, block)
-    scale = jnp.max(jnp.abs(x), axis=1) / 127.0   # (tile,)
+    scale = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0   # (tile, 1)
     safe = jnp.where(scale > 0, scale, 1.0)
-    q = jnp.clip(jnp.round(x / safe[:, None]), -127, 127)
+    q = jnp.clip(jnp.round(x / safe), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
     x_ref[...] = (q_ref[...].astype(jnp.float32)
-                  * s_ref[...][:, None]).astype(x_ref.dtype)
+                  * s_ref[...]).astype(x_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "tile", "interpret"))
@@ -46,12 +48,12 @@ def quantize_int8(x: jax.Array, *, block: int = 256, tile: int = 8,
         grid=grid,
         in_specs=[pl.BlockSpec((tile, block), lambda i: (i, 0))],
         out_specs=[pl.BlockSpec((tile, block), lambda i: (i, 0)),
-                   pl.BlockSpec((tile,), lambda i: (i,))],
+                   pl.BlockSpec((tile, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.int8),
-                   jax.ShapeDtypeStruct((nb,), jnp.float32)],
+                   jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
         interpret=interpret,
     )(panels)
-    return q, s
+    return q, s.reshape(nb)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "tile", "interpret"))
@@ -62,11 +64,11 @@ def dequantize_int8(q: jax.Array, s: jax.Array, shape: tuple[int, ...], *,
         _dequant_kernel,
         grid=(nb // tile,),
         in_specs=[pl.BlockSpec((tile, block), lambda i: (i, 0)),
-                  pl.BlockSpec((tile,), lambda i: (i,))],
+                  pl.BlockSpec((tile, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, block), jnp.float32),
         interpret=interpret,
-    )(q, s)
+    )(q, s.reshape(nb, 1))
     n = 1
     for d in shape:
         n *= d

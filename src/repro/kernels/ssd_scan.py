@@ -14,6 +14,8 @@ The pure-jnp oracle is :func:`repro.models.ssm.ssd_chunked`; tests sweep
 
 VMEM per step (Q=256, P=64, N<=128): x (Q,P) 64 KiB, B/C (Q,N) 128 KiB,
 L/CB (Q,Q) f32 256 KiB each, state (P,N) 32 KiB — well under budget.
+``dt`` moves as a lane-dense (1, Q) row per chunk and the per-head decay
+``A`` sits whole in SMEM.
 """
 
 from __future__ import annotations
@@ -23,10 +25,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
                 Q: int, nc: int):
+    h = pl.program_id(1)
     ic = pl.program_id(2)
 
     @pl.when(ic == 0)
@@ -34,31 +38,39 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)         # (Q, P)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)       # (Q,)
-    A = a_ref[0].astype(jnp.float32)               # scalar (per head)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)       # (1, Q) row
+    A = a_ref[h]                                   # f32 scalar (per head)
     Bm = b_ref[0, 0, 0].astype(jnp.float32)        # (Q, N)
     Cm = c_ref[0, 0, 0].astype(jnp.float32)        # (Q, N)
 
-    dA = dt * A                                    # (Q,) negatives
-    cum = jnp.cumsum(dA)                           # inclusive
-    total = cum[Q - 1]
+    # the chunk's prefix sums in both orientations as exact-f32 matmuls
+    # against the causal mask (Mosaic has no cumsum, and no (1, Q) ->
+    # (Q, 1) relayout); the identity turns the dt row into a column
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    mask = row >= col
+    tril = mask.astype(jnp.float32)
+    eye = (row == col).astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))
+    hi = jax.lax.Precision.HIGHEST
+    dA = dt * A                                    # (1, Q) negatives
+    cum = jax.lax.dot_general(dA, tril, nt, precision=hi)       # (1, Q)
+    cum_c = jax.lax.dot_general(tril, dA, nt, precision=hi)     # (Q, 1)
+    dt_c = jax.lax.dot_general(eye, dt, nt, precision=hi)       # (Q, 1)
+    total = jnp.sum(dA)                            # chunk's whole decay
 
-    li = cum[:, None] - cum[None, :]
-    mask = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    L = jnp.where(mask, jnp.exp(li), 0.0) * dt[None, :]
-    CB = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))   # (Q, Q)
+    L = jnp.where(mask, jnp.exp(cum_c - cum), 0.0) * dt
+    CB = jax.lax.dot_general(Cm, Bm, nt)                         # (Q, Q)
     W = CB * L
     y = jax.lax.dot_general(W, x, (((1,), (0,)), ((), ())))      # (Q, P)
 
     # inter-chunk: y += exp(cum) * (C @ state_in);  state: (P, N)
     state = state_ref[...]
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
-        Cm, state, (((1,), (1,)), ((), ())))
+    y = y + jnp.exp(cum_c) * jax.lax.dot_general(Cm, state, nt)
     # state update
-    wdt = jnp.exp(total - cum) * dt                               # (Q,)
+    wdt = jnp.exp(total - cum_c) * dt_c                          # (Q, 1)
     state_ref[...] = jnp.exp(total) * state + jax.lax.dot_general(
-        x * wdt[:, None], Bm, (((0,), (0,)), ((), ())))           # (P, N)
+        x * wdt, Bm, (((0,), (0,)), ((), ())))                   # (P, N)
 
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
@@ -77,19 +89,18 @@ def ssd_scan_bhsd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     nc = S // chunk
 
     xc = x.reshape(B, H, nc, chunk, P)
-    dtc = dt.reshape(B, H, nc, chunk)
+    dtc = dt.reshape(B, H, nc, 1, chunk)
     Bc = Bm.reshape(B, G, nc, chunk, N)
     Cc = Cm.reshape(B, G, nc, chunk, N)
 
     kernel = functools.partial(_ssd_kernel, Q=chunk, nc=nc)
-    from jax.experimental.pallas import tpu as pltpu
     y = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, chunk, P), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
+            pl.BlockSpec((1, 1, 1, 1, chunk), lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, chunk, N), lambda b, h, c: (b, h // rep, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, N), lambda b, h, c: (b, h // rep, c, 0, 0)),
         ],

@@ -41,7 +41,7 @@ from repro.core.mover import MoverConfig, UnifiedDataMover
 from repro.core.planner import plan_transfer
 from repro.core.telemetry import TelemetryRegistry, get_registry
 from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import ShapeSpec, build
 from repro.models.blocks import ShardCtx
 
@@ -110,7 +110,8 @@ class Server:
             lambda p, c, t: self.api.decode_step(p, c, t, self.ctx))
 
     def load(self, seed: int = 0) -> None:
-        self.params = self.api.init(jax.random.PRNGKey(seed))
+        # one compiled program, not one dispatch per initializer
+        self.params = jax.jit(self.api.init)(jax.random.PRNGKey(seed))
 
     def stream_basin(self):
         """The decode-stream basin, its client tier re-estimated from the
@@ -147,7 +148,10 @@ class Server:
         and the per-branch stage reports attribute a stall to the one
         slow client.  Deliveries drain through the mover's per-client
         drainer pool, so one client blocking on a write stalls only its
-        own stream while its siblings keep receiving."""
+        own stream while its siblings keep receiving.
+
+        Returns every generated token, (batch, n_tokens), whatever the
+        sinks."""
         logits, cache = self._prefill(self.params, batch)
         tok = jnp.argmax(logits[:, -1], axis=-1, keepdims=True).astype(jnp.int32)
         out = [np.asarray(tok)]
@@ -193,8 +197,14 @@ class Server:
                                  path="auto")
             mover = UnifiedDataMover(MoverConfig(checksum=False), plan=plan,
                                      telemetry=self.telemetry, layer="serve")
+
+            def deliver(item):
+                collected.append(item)
+                if one_sink is not None:
+                    one_sink(item)
+
             report = mover.streaming_transfer(
-                produce(), one_sink or collected.append, plan=plan,
+                produce(), deliver, plan=plan,
                 replan_every_items=self.replan_every_tokens)
         out.extend(collected)
         self.last_report = report
@@ -210,6 +220,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     server = Server(cfg, max_len=args.prompt_len + args.gen + 1)
     server.load()

@@ -11,7 +11,8 @@ Fault tolerance (DESIGN.md §7):
 * elastic restore: checkpoints re-shard onto whatever mesh the restarted
   job has.
 
-Usage (CPU example — full meshes need the dry-run, not execution):
+Usage (the mesh spans every local device: one TPU chip, a 2x2 host,
+or the CPU; ``chip_smoke.py`` drives this path at full width on a TPU):
   python -m repro.launch.train --arch repro-100m --steps 50 \
       --global-batch 8 --seq-len 256 --ckpt-dir /tmp/ckpt
 """
@@ -32,6 +33,7 @@ from repro.core.codesign import CodesignPlan
 from repro.core.telemetry import get_registry
 from repro.data.pipeline import InputPipeline, PipelineConfig, SyntheticTokenSource
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import build
 from repro.optim.adamw import adamw_init
@@ -123,8 +125,9 @@ class Trainer:
                     inject_failure_at = -1          # fail exactly once
                     raise RuntimeError("injected node failure")
                 t0 = time.monotonic()
-                self.params, self.opt_state, metrics = self.train_step(
-                    self.params, self.opt_state, batch)
+                # the step time covers the whole update, not the enqueue
+                self.params, self.opt_state, metrics = jax.block_until_ready(
+                    self.train_step(self.params, self.opt_state, batch))
                 loss = float(metrics["loss"])
                 dt = time.monotonic() - t0
             except RuntimeError as e:
@@ -193,6 +196,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh()
     trainer = Trainer(cfg, mesh, ckpt_dir=args.ckpt_dir,

@@ -132,18 +132,14 @@ def attention(
 
 def _try_pallas(q, k, v, *, q_pos, k_pos, causal, window) -> Optional[jax.Array]:
     """Route to the Pallas flash kernel when the shape regime fits it
-    (train/prefill: Sq == Sk, static positions)."""
-    if q.shape[1] != k.shape[1] or q.shape[1] < 128:
+    (train/prefill: Sq == Sk a multiple of 128, a static window); any
+    other regime takes the reference.  A kernel error propagates."""
+    if q.shape[1] != k.shape[1] or q.shape[1] % 128:
         return None
-    try:
-        from repro.kernels import ops as kops
-    except Exception:
-        return None
-    try:
-        return kops.flash_attention(q, k, v, causal=causal,
-                                    window=int(window) if not isinstance(window, jax.Array) else 0)
-    except (NotImplementedError, ValueError):
-        return None
+    if isinstance(window, jax.Array):
+        return None                  # per-layer window traced through scan
+    from repro.kernels import ops as kops
+    return kops.flash_attention(q, k, v, causal=causal, window=int(window))
 
 
 # ---------------------------------------------------------------------------

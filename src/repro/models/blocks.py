@@ -199,9 +199,10 @@ def stack_layers(key: jax.Array, cfg: ModelConfig, n: int, kind: str) -> dict:
     """Stacked per-layer params (leading L axis) for lax.scan."""
     init = {"attn": init_dense_layer, "moe": init_moe_layer,
             "mamba": init_mamba_layer}[kind]
-    keys = jax.random.split(key, n)
-    layers = [init(k, cfg) for k in keys]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+    # vmap over the layer keys draws the same values as a per-layer loop,
+    # as one program instead of n unrolled copies (a 32-layer init then
+    # compiles in seconds, not a minute)
+    return jax.vmap(lambda k: init(k, cfg))(jax.random.split(key, n))
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.parallel.compat import shard_map
 
 from .config import ModelConfig
 
@@ -206,7 +205,7 @@ def moe_ep(
     tok_spec = P(tok_axes if tok_axes else None, None)
     gate_up_spec = P(model_axis, fsdp, None)
     down_spec = P(model_axis, None, fsdp)
-    y, lb, z = shard_map(
+    y, lb, z = jax.shard_map(
         local, mesh=mesh,
         in_specs=(tok_spec, P(None, None), gate_up_spec, gate_up_spec, down_spec),
         out_specs=(tok_spec, P(), P()),
@@ -277,7 +276,7 @@ def moe_tp(
         return out, lb, z
 
     tok_spec = P(tok_axes if tok_axes else None, None)
-    y, lb, z = shard_map(
+    y, lb, z = jax.shard_map(
         local, mesh=mesh,
         in_specs=(tok_spec, P(None, None),
                   P(None, None, model_axis), P(None, None, model_axis),

@@ -189,10 +189,10 @@ def mamba_block_train(x: jax.Array, p: dict, cfg: ModelConfig,
     dtf = _dt_activation(dt, p["dt_bias"])                   # (B,S,H) f32
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
-    y = _try_pallas_ssd(xh, dtf, A, Bg, Cg, s.chunk) if (
-        impl == "pallas" and not return_state) else None
-    final_state = None
-    if y is None:
+    if impl == "pallas" and not return_state:
+        from repro.kernels import ops as kops
+        y, final_state = kops.ssd_scan(xh, dtf, A, Bg, Cg, chunk=s.chunk), None
+    else:
         y, final_state = ssd_chunked(xh, dtf, A, Bg, Cg, s.chunk)
     y = y + xh * p["D"].astype(x.dtype)[None, None, :, None]
     y = y.reshape(Bsz, S, cfg.d_inner)
@@ -242,10 +242,3 @@ def init_mamba_state(cfg: ModelConfig, batch: int) -> MambaState:
         ssm=jnp.zeros((batch, cfg.ssm_heads, s.head_dim, s.d_state), jnp.float32),
     )
 
-
-def _try_pallas_ssd(xh, dtf, A, Bg, Cg, chunk):
-    try:
-        from repro.kernels import ops as kops
-        return kops.ssd_scan(xh, dtf, A, Bg, Cg, chunk=chunk)
-    except Exception:
-        return None

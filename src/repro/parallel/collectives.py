@@ -30,14 +30,6 @@ from repro.optim.compression import (dequantize_int8_blockwise,
                                      quantize_int8_blockwise)
 
 
-
-def _axis_size(axis_name) -> int:
-    """jax.lax.axis_size where available; psum(1) is the portable spelling."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def compressed_psum(x: jax.Array, axis_name: str, *, block: int = 256
                     ) -> jax.Array:
     """int8-wire psum over ``axis_name`` (call inside shard_map).
@@ -50,7 +42,7 @@ def compressed_psum(x: jax.Array, axis_name: str, *, block: int = 256
       4. re-quantize the reduced chunk; all_gather int8 + scales
       5. dequant -> full reduced tensor
     """
-    g = _axis_size(axis_name)
+    g = jax.lax.axis_size(axis_name)
     if g == 1:
         return x
     orig_shape, orig_dtype = x.shape, x.dtype
@@ -88,7 +80,7 @@ def hierarchical_psum(x: jax.Array, *, intra_axis: str, inter_axis: str,
     ``inter_axis`` (DCN; optionally int8-compressed), all-gather back over
     ``intra_axis``.
     """
-    g = _axis_size(intra_axis)
+    g = jax.lax.axis_size(intra_axis)
     flat = x.reshape(-1)
     pad = (-flat.size) % g
     if pad:

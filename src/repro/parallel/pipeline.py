@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import pvary, shard_map
 
 
 def pipeline_forward(
@@ -50,8 +49,10 @@ def pipeline_forward(
         # carries must be marked device-varying over the stage axis up
         # front (ppermute outputs are varying; fori_loop carries need
         # matching types)
-        buf = pvary(jnp.zeros(mb_shape, x_local.dtype), stage_axis)
-        outs = pvary(jnp.zeros_like(x_local), stage_axis)
+        buf = jax.lax.pcast(jnp.zeros(mb_shape, x_local.dtype), stage_axis,
+                            to="varying")
+        outs = jax.lax.pcast(jnp.zeros_like(x_local), stage_axis,
+                             to="varying")
 
         def tick(t, carry):
             buf, outs = carry
@@ -85,7 +86,7 @@ def pipeline_forward(
 
     # stage axis shards the layer dim of every stacked leaf
     param_spec = jax.tree.map(lambda _: P(stage_axis), stacked_params)
-    return shard_map(
+    return jax.shard_map(
         staged, mesh=mesh,
         in_specs=(param_spec, P(*( [None] * x.ndim ))),
         out_specs=P(*([None] * x.ndim)),

@@ -22,7 +22,6 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch.mesh import _make_mesh
-from repro.parallel.compat import shard_map
 mesh = _make_mesh((2, 4), ("data", "model"))
 
 # --- 1. sharding rules: specs valid + divisible ---------------------------
@@ -95,19 +94,19 @@ print("MARKER moe-parity-ok")
 # --- 4. compressed + hierarchical psum match plain psum -------------------
 from repro.parallel.collectives import compressed_psum, hierarchical_psum
 data = jax.random.normal(jax.random.PRNGKey(4), (4, 512))
-exact = shard_map(lambda v: jax.lax.psum(v, "model"), mesh=mesh,
+exact = jax.shard_map(lambda v: jax.lax.psum(v, "model"), mesh=mesh,
                       in_specs=P("model", None), out_specs=P(None, None))(data)
-approx = shard_map(lambda v: compressed_psum(v, "model", block=64),
+approx = jax.shard_map(lambda v: compressed_psum(v, "model", block=64),
                        mesh=mesh, in_specs=P("model", None),
                        out_specs=P(None, None), check_vma=False)(data)
 rel = np.abs(np.asarray(approx) - np.asarray(exact)).max() / (
     np.abs(np.asarray(exact)).max() + 1e-9)
 assert rel < 0.05, rel
-hier = shard_map(lambda v: hierarchical_psum(
+hier = jax.shard_map(lambda v: hierarchical_psum(
     v, intra_axis="model", inter_axis="data"), mesh=mesh,
     in_specs=P(("data", "model"), None), out_specs=P(None, None),
     check_vma=False)(jnp.tile(data, (2, 1)))
-exact2 = shard_map(lambda v: jax.lax.psum(v, ("data", "model")),
+exact2 = jax.shard_map(lambda v: jax.lax.psum(v, ("data", "model")),
                        mesh=mesh, in_specs=P(("data", "model"), None),
                        out_specs=P(None, None))(jnp.tile(data, (2, 1)))
 np.testing.assert_allclose(np.asarray(hier), np.asarray(exact2),

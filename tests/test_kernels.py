@@ -45,16 +45,17 @@ def test_flash_attention_sweep(B, S, Hq, Hkv, hd, dtype, causal, window):
     (2, 256, 4, 2, 32, 255),
     (1, 512, 8, 2, 64, 300),
     (3, 128, 4, 4, 64, 17),     # partially filled cache
+    (4, 256, 4, 2, 32, (200, 131, 62, 9)),   # each sequence at its own position
 ])
 @pytest.mark.parametrize("window", [0, 96])
 def test_decode_attention_sweep(B, S, Hq, Hkv, hd, fill, window):
-    k = jax.random.PRNGKey(S + fill)
+    k = jax.random.PRNGKey(S + int(np.max(fill)))
     q = _rand(k, (B, Hq, hd))
     kc = _rand(jax.random.fold_in(k, 1), (B, Hkv, S, hd))
     vc = _rand(jax.random.fold_in(k, 2), (B, Hkv, S, hd))
     pos = jnp.broadcast_to(jnp.arange(S), (B, S)).astype(jnp.int32)
-    k_pos = jnp.where(pos <= fill, pos, -1)
-    q_pos = jnp.full((B,), fill, jnp.int32)
+    q_pos = jnp.broadcast_to(jnp.asarray(fill, jnp.int32), (B,))
+    k_pos = jnp.where(pos <= q_pos[:, None], pos, -1)
     out = decode_attention_bhd(q, kc, vc, k_pos, q_pos, window=window,
                                bk=128, interpret=True)
     expect = ref.decode_attention_ref(q, kc, vc, k_pos, q_pos, window=window)

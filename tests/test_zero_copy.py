@@ -299,13 +299,21 @@ def test_slab_fold_equals_per_item_fold():
 
 
 def test_accel_digest_pallas_matches_ref_backend():
+    """The Pallas digest kernel (interpreted off the TPU) and the jnp
+    oracle give the same bits on a stream's panels, so the accel checksum
+    does not depend on which of the two computes it."""
+    import numpy as np
+    from repro.core.integrity import _item_words
+    from repro.kernels import ops
+    from repro.kernels.digest import digest_ref
     items = [os.urandom(ITEM) for _ in range(5)] + [os.urandom(37)]
-    ref, pal = (StreamDigest(True, placement="accel", backend="ref"),
-                StreamDigest(True, placement="accel", backend="pallas"))
-    ref.many(items)
-    pal.many(items)
-    assert ref.hexdigest() == pal.hexdigest()
-    assert ref.hexdigest().startswith("u32:")
+    # 41 block rows: not a whole number of kernel tiles, so the pad runs
+    panels = np.concatenate([_item_words(it)[0] for it in items])
+    assert np.array_equal(np.asarray(ops.block_digest(panels)),
+                          np.asarray(digest_ref(panels)))
+    d = StreamDigest(True, placement="accel")
+    d.many(items)
+    assert d.hexdigest().startswith("u32:")
 
 
 def test_disabled_digest_is_a_noop():
