@@ -27,6 +27,7 @@ Usage (CPU smoke):
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 from typing import Any, Iterator, Optional
 
@@ -86,6 +87,10 @@ def observed_client_gbps(registry: TelemetryRegistry) -> Optional[float]:
     return max(MIN_CLIENT_GBPS, (sum(window) / len(window)) * 8.0 / 1e9)
 
 
+def _no_span(name: str, **meta):
+    return contextlib.nullcontext()
+
+
 class Server:
     """Holds params + compiled prefill/decode; streams tokens out through
     a burst buffer."""
@@ -104,6 +109,7 @@ class Server:
         self.ctx = (steps_lib.make_ctx(self.api, mesh, self.plan)
                     if mesh is not None else ShardCtx())
         self.params = None
+        self._requests = 0          # generate calls, the spans' ``request``
         self._prefill = jax.jit(
             lambda p, b: self.api.prefill(p, b, self.ctx, max_len=max_len))
         self._decode = jax.jit(
@@ -150,30 +156,52 @@ class Server:
         drainer pool, so one client blocking on a write stalls only its
         own stream while its siblings keep receiving.
 
+        With one sink the request opens profiler spans, each with its
+        ``request`` (the count of ``generate`` calls) and, per decode step,
+        its ``step``: ``serve.start`` from entry until the stream starts,
+        then per step ``serve.dispatch`` (the decode call and its argmax),
+        ``serve.fetch`` (the token's host copy) and ``serve.deliver``
+        (the sink).  With no profiler session each costs about a
+        microsecond.
+
         Returns every generated token, (batch, n_tokens), whatever the
         sinks."""
-        logits, cache = self._prefill(self.params, batch)
-        tok = jnp.argmax(logits[:, -1], axis=-1, keepdims=True).astype(jnp.int32)
-        out = [np.asarray(tok)]
-        n_batch = int(tok.shape[0])
-
-        def produce() -> Iterator[np.ndarray]:
-            nonlocal tok, cache
-            for _ in range(n_tokens - 1):
-                logits_i, cache = self._decode(self.params, cache, tok)
-                tok = jnp.argmax(logits_i[:, -1], axis=-1,
-                                 keepdims=True).astype(jnp.int32)
-                yield np.asarray(tok)
-
+        request = self._requests
+        self._requests += 1
         sinks = list(sink) if isinstance(sink, (list, tuple)) else None
-        collected: list[np.ndarray] = []
-        if sinks and len(sinks) > 1:
-            plan = plan_transfer(self.fanout_basin(len(sinks)),
-                                 item_bytes=max(1, n_batch * 4),
+        fan_out = bool(sinks) and len(sinks) > 1
+        # profiler spans on the single-sink path; the fan-out path has no
+        # benchmark cell to read them yet
+        span = _no_span if fan_out else jax.profiler.TraceAnnotation
+        with span("serve.start", request=request):
+            logits, cache = self._prefill(self.params, batch)
+            tok = jnp.argmax(logits[:, -1], axis=-1,
+                             keepdims=True).astype(jnp.int32)
+            out = [np.asarray(tok)]
+            n_batch = int(tok.shape[0])
+            basin = (self.fanout_basin(len(sinks)) if fan_out
+                     else self.stream_basin())
+            plan = plan_transfer(basin, item_bytes=max(1, n_batch * 4),
                                  stages=("token-stream",), ordered=True,
                                  path="auto")
             mover = UnifiedDataMover(MoverConfig(checksum=False), plan=plan,
                                      telemetry=self.telemetry, layer="serve")
+
+        def produce() -> Iterator[np.ndarray]:
+            nonlocal tok, cache
+            for step in range(n_tokens - 1):
+                with span("serve.dispatch", request=request, step=step):
+                    logits_i, cache = self._decode(self.params, cache, tok)
+                    tok = jnp.argmax(logits_i[:, -1], axis=-1,
+                                     keepdims=True).astype(jnp.int32)
+                with span("serve.fetch", request=request, step=step):
+                    host = np.asarray(tok)
+                # yielded outside the fetch span: the handoff to the
+                # mover is not the fetch
+                yield host
+
+        collected: list[np.ndarray] = []
+        if fan_out:
             # branch order follows basin link order == client order
             sink_map = {b.branch_id: s
                         for b, s in zip(plan.branches, sinks)}
@@ -191,17 +219,13 @@ class Server:
                 drainer_pool=True)
         else:
             one_sink = sinks[0] if sinks else sink
-            plan = plan_transfer(self.stream_basin(),
-                                 item_bytes=max(1, n_batch * 4),
-                                 stages=("token-stream",), ordered=True,
-                                 path="auto")
-            mover = UnifiedDataMover(MoverConfig(checksum=False), plan=plan,
-                                     telemetry=self.telemetry, layer="serve")
 
             def deliver(item):
-                collected.append(item)
-                if one_sink is not None:
-                    one_sink(item)
+                with span("serve.deliver", request=request,
+                          step=len(collected)):
+                    collected.append(item)
+                    if one_sink is not None:
+                        one_sink(item)
 
             report = mover.streaming_transfer(
                 produce(), deliver, plan=plan,
