@@ -112,8 +112,11 @@ class Server:
         self._requests = 0          # generate calls, the spans' ``request``
         self._prefill = jax.jit(
             lambda p, b: self.api.prefill(p, b, self.ctx, max_len=max_len))
+        # the cache is donated: a step writes its K/V into it in place, so
+        # a cache passed in is never read again
         self._decode = jax.jit(
-            lambda p, c, t: self.api.decode_step(p, c, t, self.ctx))
+            lambda p, c, t: self.api.decode_step(p, c, t, self.ctx),
+            donate_argnums=(1,))
 
     def load(self, seed: int = 0) -> None:
         # one compiled program, not one dispatch per initializer

@@ -17,7 +17,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.codesign import CodesignPlan
 from repro.models.api import ModelApi, ShapeSpec
-from repro.models.blocks import ShardCtx
+from repro.models.blocks import ShardCtx, kv_cache_spec
 from repro.optim.adamw import AdamWState, adamw_init, adamw_update, warmup_cosine
 from repro.parallel.sharding import batch_axes_of, param_shardings
 
@@ -182,10 +182,9 @@ def _dp(mesh: Mesh) -> int:
 def cache_shardings(cache_abs: Any, mesh: Mesh) -> Any:
     """Decode-cache shardings by leaf kind.
 
-    KV-like leaves (L, B, S, H, hd): batch over the data axes when it
-    divides, else the *sequence* shards over data (long-context batch=1);
-    heads over model when divisible.  Mamba states (L, B, ...): batch over
-    data, feature dims over model when divisible.  Scalars replicated.
+    Attention caches, (L, B, S, H, hd) or heads flat (L, B, S, H * hd):
+    ``blocks.kv_cache_spec``.  Mamba states (L, B, ...): batch over data,
+    feature dims over model when divisible.  Scalars replicated.
     """
     axes = batch_axes_of(mesh)
     dp = _dp(mesh)
@@ -196,20 +195,9 @@ def cache_shardings(cache_abs: Any, mesh: Mesh) -> Any:
         if nd == 0:
             return NamedSharding(mesh, P())
         name = str(getattr(path[-1], "key", getattr(path[-1], "name", "")))
-        if nd == 5:          # (L, B, S, H, hd) attention caches
-            L, B, S, H, _ = v.shape
-            b_ax = axes if (B % dp == 0 and B >= dp) else None
-            h_ax = "model" if H % m == 0 else None
-            # when heads can't shard, the sequence takes the model axis
-            # (flash-decode partials combine via psum); with batch also
-            # unshardable the sequence takes the data axes instead
-            if h_ax is None and S % m == 0:
-                s_ax = "model"
-            elif b_ax is None and S % dp == 0:
-                s_ax = axes
-            else:
-                s_ax = None
-            return NamedSharding(mesh, P(None, b_ax, s_ax, h_ax, None))
+        if nd == 5 or name in ("k", "v", "shared_k", "shared_v"):
+            return NamedSharding(mesh, kv_cache_spec(v.shape, dp, m, axes,
+                                                     "model"))
         if nd == 4 and name in ("conv", ""):   # (L, B, W, C) conv state
             L, B, W, C = v.shape
             b_ax = axes if (B % dp == 0 and B >= dp) else None

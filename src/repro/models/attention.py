@@ -7,7 +7,8 @@ One implementation covers every assigned pattern:
 * 5:1 local:global interleave (gemma3 — per-layer window passed as data
   through the layer scan, so the stacked-layer scan stays homogeneous),
 * bidirectional (seamless encoder), cross-attention (seamless decoder),
-* single-query decode against a (possibly ring) KV cache.
+* single-query decode against a (possibly ring) KV cache that it only
+  reads (``attention_decode``).
 
 Positions are explicit everywhere: a KV slot with position < 0 is invalid
 (empty ring-buffer slot).  Window masking is relative: key valid iff
@@ -130,6 +131,66 @@ def attention(
     return jnp.moveaxis(outs, 0, 1).reshape(B, Sq, Hq, hd)
 
 
+def attention_decode(
+    q: jax.Array,            # (B, 1, Hq, hd)
+    k_cache: jax.Array,      # (B, Sk, Hkv * hd)
+    v_cache: jax.Array,      # (B, Sk, Hkv * hd)
+    k_new: jax.Array,        # (B, 1, Hkv, hd)
+    v_new: jax.Array,        # (B, 1, Hkv, hd)
+    *,
+    q_pos: jax.Array,        # (1,)
+    k_pos: jax.Array,        # (Sk,) cache slot positions, -1 invalid
+    window: int | jax.Array = 0,
+) -> jax.Array:
+    """Single-query decode attention over a cache it only reads, with the
+    step's own key and value as one more column of the same softmax;
+    returns (B, 1, Hq, hd).
+
+    ``k_pos`` must mark invalid whatever the step would overwrite: then
+    the keys and mask are those of writing the step into the cache and
+    attending to it, so the caller can store ``k_new``/``v_new`` for every
+    layer in one write.  The cache keeps each position's heads flat, so
+    that write is one contiguous row per sequence whatever ``hd``; the
+    queries enter as a block-diagonal (Hkv * hd, Hq) matrix, and each
+    head's output is its own block of P.V, so the cache is read as
+    stored, never relaid out.  That costs Hkv times the two products'
+    FLOPs: Hq FLOPs per cache byte read.  Both P.V products accumulate in
+    f32 and round once, as one einsum would.
+    """
+    with jax.named_scope("flashable_attention"):
+        B, _, Hq, hd = q.shape
+        Hkv = k_new.shape[2]
+        G = Hq // Hkv
+        qg = q.reshape(B, Hkv, G, hd)
+        # an exact product (each term is q times 1 or 0)
+        q_bd = jnp.einsum("bhgd,hk->bkdhg", qg, jnp.eye(Hkv, dtype=q.dtype)
+                          ).reshape(B, Hkv * hd, Hq)
+        k_new = k_new.reshape(B, Hkv, hd).astype(k_cache.dtype)
+        v_new = v_new.reshape(B, Hkv, hd).astype(v_cache.dtype)
+        scale = hd ** -0.5
+        s_cache = jnp.einsum("bsc,bcj->bjs", k_cache, q_bd,
+                             preferred_element_type=jnp.float32) * scale
+        mask = _build_mask(q_pos, k_pos, causal=True, window=window)
+        s_cache = jnp.where(mask, s_cache, NEG_INF)
+        s_new = jnp.einsum("bhgd,bhd->bhg", qg, k_new,
+                           preferred_element_type=jnp.float32
+                           ).reshape(B, Hq, 1) * scale
+        m = jnp.maximum(jnp.max(s_cache, axis=-1, keepdims=True), s_new)
+        e_cache = jnp.exp(s_cache - m)
+        e_new = jnp.exp(s_new - m)
+        denom = jnp.sum(e_cache, axis=-1, keepdims=True) + e_new
+        pv = jnp.einsum("bjs,bsc->bjc", (e_cache / denom).astype(q.dtype),
+                        v_cache, preferred_element_type=jnp.float32)
+        # each head's own block, selected exactly (one nonzero term)
+        diag = jnp.eye(Hkv, dtype=bool)[:, None, :, None]
+        out = jnp.where(diag, pv.reshape(B, Hkv, G, Hkv, hd), 0.).sum(axis=3)
+        out = out + jnp.einsum(
+            "bhg,bhd->bhgd",
+            (e_new / denom).astype(q.dtype).reshape(B, Hkv, G), v_new,
+            preferred_element_type=jnp.float32)
+        return out.astype(q.dtype).reshape(B, 1, Hq, hd)
+
+
 def _try_pallas(q, k, v, *, q_pos, k_pos, causal, window) -> Optional[jax.Array]:
     """Route to the Pallas flash kernel when the shape regime fits it
     (train/prefill: Sq == Sk a multiple of 128, a static window); any
@@ -162,18 +223,6 @@ def cache_positions_full(s_max: int, pos: jax.Array) -> jax.Array:
     """Absolute positions of full-cache slots; > pos slots invalid (-1)."""
     idx = jnp.arange(s_max, dtype=jnp.int32)
     return jnp.where(idx <= pos, idx, -1)
-
-
-def cache_update_ring(k_cache: jax.Array, v_cache: jax.Array,
-                      k_new: jax.Array, v_new: jax.Array,
-                      pos: jax.Array, window: int) -> tuple[jax.Array, jax.Array]:
-    """Write into a ring cache of length `window` at slot pos % window."""
-    slot = jnp.mod(pos, window)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, k_new.astype(k_cache.dtype), slot, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, v_new.astype(v_cache.dtype), slot, axis=1)
-    return k_cache, v_cache
 
 
 def cache_positions_ring(window: int, pos: jax.Array) -> jax.Array:

@@ -22,6 +22,28 @@ from .common import apply_rope, dense_init, rms_norm
 from .config import ModelConfig
 
 
+def kv_cache_spec(shape: tuple[int, ...], dp: int, m: int,
+                  batch_axes: Any, model_axis: str) -> P:
+    """Sharding of a stacked attention cache, (L, B, S, Hkv, hd) or heads
+    flat, (L, B, S, Hkv * hd): batch over the data axes (``dp`` wide) when
+    it divides; heads over the model axis (``m`` wide) when they divide;
+    otherwise the *sequence* takes the model axis (flash-decode partials
+    combine via psum), or the data axes with batch also unshardable
+    (long_500k).  A flat row never splits: the decode attention contracts
+    over all of it, and XLA would gather each layer's cache to do so."""
+    B, S = shape[1], shape[2]
+    heads = len(shape) == 5 and m > 1 and shape[3] % m == 0
+    b_ax = batch_axes if (B % dp == 0 and B >= dp) else None
+    if not heads and m > 1 and S % m == 0:
+        s_ax = model_axis
+    elif b_ax is None and S % dp == 0:
+        s_ax = batch_axes
+    else:
+        s_ax = None
+    return P(None, b_ax, s_ax, model_axis if heads else None,
+             *([None] * (len(shape) - 4)))
+
+
 @dataclasses.dataclass(frozen=True)
 class ShardCtx:
     """Mesh context for activation sharding + manual-collective blocks."""
@@ -85,27 +107,16 @@ class ShardCtx:
                 x, P(self.batch_axes, self.model_axis, None, None))
         return self._constrain(x, P(self.batch_axes, None, None, None))
 
-    def shard_kv_cache(self, x: jax.Array, *, seq_axis: int = 1) -> jax.Array:
-        """(B, S, Hkv, hd) cache: batch over data axes when divisible;
-        heads over model when divisible, otherwise the *sequence* takes the
-        model axis (flash-decode partials combine via psum); with batch
-        also unshardable (long_500k) the sequence takes the data axes."""
+    def shard_kv_cache(self, x: jax.Array) -> jax.Array:
+        """A stacked attention cache, sharded by ``kv_cache_spec``."""
         if self.mesh is None:
             return x
-        b, s, h = x.shape[0], x.shape[seq_axis], x.shape[2]
         dp = 1
         for a in self.batch_axes:
             dp *= self.mesh.shape[a]
-        m = self._model_size()
-        head_spec = self.model_axis if (m > 1 and h % m == 0) else None
-        b_spec = self.batch_axes if (b % dp == 0 and b >= dp) else None
-        if head_spec is None and m > 1 and s % m == 0:
-            s_spec = self.model_axis
-        elif b_spec is None and s % dp == 0:
-            s_spec = self.batch_axes
-        else:
-            s_spec = None
-        return self._constrain(x, P(b_spec, s_spec, head_spec, None))
+        return self._constrain(x, kv_cache_spec(
+            x.shape, dp, self._model_size(), self.batch_axes,
+            self.model_axis))
 
     def choose_moe(self, cfg: ModelConfig) -> str:
         if self.moe_impl != "auto":

@@ -170,8 +170,7 @@ def cross_kv(params: dict, cfg: ModelConfig, enc_out: jax.Array, ctx: ShardCtx
     kc = kc.reshape(shape).astype(jnp.bfloat16)
     vc = vc.reshape(shape).astype(jnp.bfloat16)
     if ctx.mesh is not None:
-        kc = jax.tree.map(lambda a: ctx.shard_kv_cache(a, seq_axis=2), kc)
-        vc = jax.tree.map(lambda a: ctx.shard_kv_cache(a, seq_axis=2), vc)
+        kc, vc = ctx.shard_kv_cache(kc), ctx.shard_kv_cache(vc)
     return kc, vc
 
 
@@ -183,10 +182,10 @@ def init_encdec_cache(cfg: ModelConfig, batch: int, max_len: int,
     ckv = jnp.zeros((L, batch, enc_len, cfg.n_kv_heads, cfg.hd), jnp.bfloat16)
     return {
         "pos": jnp.zeros((), jnp.int32),
-        "k": ctx.shard_kv_cache(kv, seq_axis=2),
-        "v": ctx.shard_kv_cache(kv, seq_axis=2),
-        "cross_k": ctx.shard_kv_cache(ckv, seq_axis=2),
-        "cross_v": ctx.shard_kv_cache(ckv, seq_axis=2),
+        "k": ctx.shard_kv_cache(kv),
+        "v": ctx.shard_kv_cache(kv),
+        "cross_k": ctx.shard_kv_cache(ckv),
+        "cross_v": ctx.shard_kv_cache(ckv),
     }
 
 

@@ -9,8 +9,14 @@ Heterogeneity is handled without breaking the scan:
 * zamba2's shared attention block (one set of weights applied every
   ``attn_every`` layers) splits the Mamba stack into segments, scanning
   each segment and applying the shared block between segments,
-* decode caches travel through the scan as xs/ys (sliced per layer on the
-  way in, restacked on the way out), keeping serve_step compile-time flat.
+* decode caches enter the scan as read-only xs; each layer's new K/V
+  leaves as a small ys and is written into the stacked cache once after
+  the scan (in place where the caller donates the cache), keeping
+  serve_step compile-time flat.  Attention caches hold each position's
+  heads flat, (L, B, S, Hkv * hd): the TPU lays a (.., S, Hkv, hd) cache
+  out with S minor-most whenever hd is not a multiple of 128, so that one
+  position's write touches every tile, and reads it per head more slowly
+  than the flat rows even where hd is 128 (PERF.md, PR 14).
 
 Remat policy (cfg.remat): 'full' checkpoints each layer body (only layer
 boundaries persist for backward), 'dots' saves matmul outputs, 'none'
@@ -26,8 +32,8 @@ import jax
 import jax.numpy as jnp
 
 from . import ssm as ssm_lib
-from .attention import (attention, cache_positions_full, cache_positions_ring,
-                        cache_update_full, cache_update_ring)
+from .attention import (attention_decode, cache_positions_full,
+                        cache_positions_ring)
 from .blocks import (ShardCtx, dense_layer_apply, init_dense_layer,
                      init_mamba_layer, init_moe_layer, moe_layer_apply,
                      stack_layers)
@@ -262,11 +268,13 @@ def prefill_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
                 y = ffn_lib.swiglu(h2, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
                                    lp["mlp"]["w_down"])
             h = ctx.shard_act(h + y)
+            k_new = k_new.reshape(B, S, cfg.kv_dim)
+            v_new = v_new.reshape(B, S, cfg.kv_dim)
             if ring:
                 k_c = _ring_pack(k_new, s_cache)
                 v_c = _ring_pack(v_new, s_cache)
             else:
-                pad = [(0, 0)] * 4
+                pad = [(0, 0)] * 3
                 pad[1] = (0, max_len - S)
                 k_c = jnp.pad(k_new, pad)
                 v_c = jnp.pad(v_new, pad)
@@ -304,7 +312,7 @@ def prefill_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
 def _hybrid_prefill(params, cfg, x, ctx, positions, windows, cache, s_cache):
     from .blocks import self_attention_block
     from . import ffn as ffn_lib
-    S = x.shape[1]
+    B, S = x.shape[:2]
     conv_out, ssm_out, k_sites, v_sites = [], [], [], []
 
     def seg_body(h, xs):
@@ -331,11 +339,13 @@ def _hybrid_prefill(params, cfg, x, ctx, positions, windows, cache, s_cache):
         x = ctx.shard_act(x + ffn_lib.swiglu(h2, sp["mlp"]["w_gate"],
                                              sp["mlp"]["w_up"],
                                              sp["mlp"]["w_down"]))
+        k_new = k_new.reshape(B, S, cfg.kv_dim)
+        v_new = v_new.reshape(B, S, cfg.kv_dim)
         if cfg.window > 0:
             k_sites.append(_ring_pack(k_new, s_cache).astype(jnp.bfloat16))
             v_sites.append(_ring_pack(v_new, s_cache).astype(jnp.bfloat16))
         else:
-            pad = [(0, 0)] * 4
+            pad = [(0, 0)] * 3
             pad[1] = (0, cache["shared_k"].shape[2] - S)
             k_sites.append(jnp.pad(k_new, pad).astype(jnp.bfloat16))
             v_sites.append(jnp.pad(v_new, pad).astype(jnp.bfloat16))
@@ -393,9 +403,9 @@ def init_lm_cache(cfg: ModelConfig, batch: int, max_len: int,
     L = cfg.n_layers
     if cfg.family in ("dense", "vlm", "moe"):
         s = _attn_cache_len(cfg, max_len)
-        kv = jnp.zeros((L, batch, s, cfg.n_kv_heads, cfg.hd), jnp.bfloat16)
-        cache["k"] = ctx.shard_kv_cache(kv, seq_axis=2)
-        cache["v"] = ctx.shard_kv_cache(kv, seq_axis=2)
+        kv = jnp.zeros((L, batch, s, cfg.kv_dim), jnp.bfloat16)
+        cache["k"] = ctx.shard_kv_cache(kv)
+        cache["v"] = ctx.shard_kv_cache(kv)
     elif cfg.family in ("ssm", "hybrid"):
         st = ssm_lib.init_mamba_state(cfg, batch)
         cache["mamba"] = ssm_lib.MambaState(
@@ -406,19 +416,18 @@ def init_lm_cache(cfg: ModelConfig, batch: int, max_len: int,
             n_sites = len(_segment_bounds(cfg.n_layers,
                                           cfg.attn_every or cfg.n_layers))
             s = min(cfg.window, max_len) if cfg.window > 0 else max_len
-            kv = jnp.zeros((n_sites, batch, s, cfg.n_kv_heads, cfg.hd),
-                           jnp.bfloat16)
-            cache["shared_k"] = ctx.shard_kv_cache(kv, seq_axis=2)
-            cache["shared_v"] = ctx.shard_kv_cache(kv, seq_axis=2)
+            kv = jnp.zeros((n_sites, batch, s, cfg.kv_dim), jnp.bfloat16)
+            cache["shared_k"] = ctx.shard_kv_cache(kv)
+            cache["shared_v"] = ctx.shard_kv_cache(kv)
     return cache
 
 
 def _decode_attn_block(x, lp, cfg, ctx, k_cache, v_cache, pos, window,
-                       ring_len: int):
-    """One decode step through one attention layer against its cache.
-    Returns (x_out, k_cache', v_cache')."""
-    from .blocks import self_attention_block  # local to avoid cycle at import
-
+                       ring: bool):
+    """One decode step through one attention layer, reading its cache
+    (B, S, Hkv * hd), which does not hold step ``pos`` yet.  Returns
+    (x_out, k, v): the step's key and value, (B, 1, Hkv * hd) in the
+    cache's dtype, for the caller to store (``_store_step``)."""
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     B = x.shape[0]
     q_pos = jnp.broadcast_to(pos, (1,)).astype(jnp.int32)
@@ -430,19 +439,27 @@ def _decode_attn_block(x, lp, cfg, ctx, k_cache, v_cache, pos, window,
         B, 1, cfg.n_kv_heads, cfg.hd)
     q = apply_rope(q, q_pos, cfg.rope_theta)
     k = apply_rope(k, q_pos, cfg.rope_theta)
-    s_cache = k_cache.shape[1]
-    if ring_len > 0:
-        k_cache, v_cache = cache_update_ring(k_cache, v_cache, k, v, pos,
-                                             ring_len)
-        k_pos = cache_positions_ring(ring_len, pos)
-    else:
-        k_cache, v_cache = cache_update_full(k_cache, v_cache, k, v, pos)
-        k_pos = cache_positions_full(s_cache, pos)
-    out = attention(q, k_cache, v_cache, q_pos=q_pos, k_pos=k_pos,
-                    causal=True, window=window, impl="ref")
+    positions = cache_positions_ring if ring else cache_positions_full
+    # the slots as writing step `pos` would leave them, less the step
+    # itself, which attends as its own column
+    k_pos = positions(k_cache.shape[1], pos)
+    k_pos = jnp.where(k_pos < pos, k_pos, -1)
+    out = attention_decode(q, k_cache, v_cache, k, v, q_pos=q_pos,
+                           k_pos=k_pos, window=window)
     out = out.reshape(B, 1, cfg.q_dim)
     x = x + jnp.einsum("bsq,qd->bsd", out, lp["attn"]["wo"])
-    return x, k_cache, v_cache
+    return (x, k.reshape(B, 1, cfg.kv_dim).astype(k_cache.dtype),
+            v.reshape(B, 1, cfg.kv_dim).astype(v_cache.dtype))
+
+
+def _store_step(k_cache, v_cache, k_step, v_step, pos, ring: bool):
+    """Write every layer's step-``pos`` K/V, (L, B, 1, Hkv * hd), into the
+    stacked caches (L, B, S, Hkv * hd): one update each, at slot ``pos``
+    or, in a ring, ``pos`` modulo the cache's own slot count."""
+    slot = jnp.mod(pos, k_cache.shape[2]) if ring else pos
+    at = (0, 0, slot, 0)
+    return (jax.lax.dynamic_update_slice(k_cache, k_step, at),
+            jax.lax.dynamic_update_slice(v_cache, v_step, at))
 
 
 def lm_decode_step(params: dict, cfg: ModelConfig, cache: dict,
@@ -454,7 +471,7 @@ def lm_decode_step(params: dict, cfg: ModelConfig, cache: dict,
     pos = cache["pos"]
     x = ctx.shard_act(params["embed"][tokens])
     new_cache = dict(cache)
-    ring = cfg.window if cache_kind(cfg) == "ring" else 0
+    ring = cache_kind(cfg) == "ring"
     windows = jnp.asarray(cfg.layer_windows(), jnp.int32)
 
     if cfg.family in ("dense", "vlm", "moe"):
@@ -462,7 +479,7 @@ def lm_decode_step(params: dict, cfg: ModelConfig, cache: dict,
 
         def body(h, xs):
             lp, k_l, v_l, w = xs
-            h, k_l, v_l = _decode_attn_block(h, lp, cfg, ctx, k_l, v_l, pos,
+            h, k_t, v_t = _decode_attn_block(h, lp, cfg, ctx, k_l, v_l, pos,
                                              w, ring)
             h2 = rms_norm(h, lp["ln2"], cfg.norm_eps)
             if is_moe:
@@ -487,11 +504,12 @@ def lm_decode_step(params: dict, cfg: ModelConfig, cache: dict,
             else:
                 y = ffn_lib.swiglu(h2, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
                                    lp["mlp"]["w_down"])
-            return h + y, (k_l, v_l)
+            return h + y, (k_t, v_t)
 
-        x, (k_new, v_new) = jax.lax.scan(
+        x, (k_step, v_step) = jax.lax.scan(
             body, x, (params["layers"], cache["k"], cache["v"], windows))
-        new_cache["k"], new_cache["v"] = k_new, v_new
+        new_cache["k"], new_cache["v"] = _store_step(
+            cache["k"], cache["v"], k_step, v_step, pos, ring)
 
     elif cfg.family == "ssm":
         def body(h, xs):
@@ -522,7 +540,7 @@ def _hybrid_decode(params, cfg, cache, x, ctx, pos):
 
     new_cache = dict(cache)
     bounds = _segment_bounds(cfg.n_layers, cfg.attn_every or cfg.n_layers)
-    ring = cfg.window if cfg.window > 0 else 0
+    ring = cfg.window > 0
     conv_all, ssm_all = cache["mamba"].conv, cache["mamba"].ssm
     conv_out, ssm_out = [], []
     k_sites, v_sites = [], []
@@ -543,19 +561,19 @@ def _hybrid_decode(params, cfg, cache, x, ctx, pos):
         ssm_out.append(ssm_n)
         # shared attention block at the segment boundary
         sp = params["shared_attn"]
-        k_l = cache["shared_k"][i]
-        v_l = cache["shared_v"][i]
-        x, k_l, v_l = _decode_attn_block(x, sp, cfg, ctx, k_l, v_l, pos,
+        x, k_t, v_t = _decode_attn_block(x, sp, cfg, ctx, cache["shared_k"][i],
+                                         cache["shared_v"][i], pos,
                                          cfg.window, ring)
         h2 = rms_norm(x, sp["ln2"], cfg.norm_eps)
         x = x + ffn_lib.swiglu(h2, sp["mlp"]["w_gate"], sp["mlp"]["w_up"],
                                sp["mlp"]["w_down"])
-        k_sites.append(k_l)
-        v_sites.append(v_l)
+        k_sites.append(k_t)
+        v_sites.append(v_t)
 
     new_cache["mamba"] = ssm_lib.MambaState(
         conv=jnp.concatenate(conv_out, axis=0),
         ssm=jnp.concatenate(ssm_out, axis=0))
-    new_cache["shared_k"] = jnp.stack(k_sites)
-    new_cache["shared_v"] = jnp.stack(v_sites)
+    new_cache["shared_k"], new_cache["shared_v"] = _store_step(
+        cache["shared_k"], cache["shared_v"], jnp.stack(k_sites),
+        jnp.stack(v_sites), pos, ring)
     return x, new_cache

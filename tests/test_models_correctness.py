@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from repro.models import ModelConfig, MoEConfig, SSMConfig, ShardCtx, build
-from repro.models.attention import (attention, cache_positions_ring,
+from repro.models.attention import (attention, attention_decode,
+                                    cache_positions_ring,
                                     cache_positions_full)
 from repro.models.lm import forward_lm
 
@@ -83,6 +84,29 @@ def test_chunked_attention_matches_unchunked():
                                np.asarray(chunked, np.float32), atol=2e-5)
 
 
+def test_attention_decode_matches_attention():
+    """Decode attention over a cache it only reads, with the step as one
+    more column, equals attention over the cache with the step written."""
+    B, S, Hq, Hkv, hd, pos = 2, 12, 6, 3, 16, 7
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    q = jax.random.normal(ks[0], (B, 1, Hq, hd), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (B, S, Hkv, hd), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (B, S, Hkv, hd), jnp.bfloat16)
+    k_new = jax.random.normal(ks[3], (B, 1, Hkv, hd), jnp.bfloat16)
+    v_new = jax.random.normal(ks[4], (B, 1, Hkv, hd), jnp.bfloat16)
+    q_pos = jnp.asarray([pos])
+    k_pos = cache_positions_full(S, jnp.asarray(pos))
+    want = attention(q, k.at[:, pos].set(k_new[:, 0]),
+                     v.at[:, pos].set(v_new[:, 0]), q_pos=q_pos, k_pos=k_pos,
+                     window=5)
+    rows = (B, S, Hkv * hd)
+    got = attention_decode(q, k.reshape(rows), v.reshape(rows), k_new, v_new,
+                           q_pos=q_pos, k_pos=jnp.where(k_pos < pos, k_pos, -1),
+                           window=5)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+
+
 def test_ring_positions():
     # after writing pos=9 with window 4, slots hold positions 8,9,6,7
     got = np.asarray(cache_positions_ring(4, jnp.asarray(9)))
@@ -105,6 +129,7 @@ CONSISTENCY_CASES = [
     _mk("dense"),
     _mk("swa", window=8),
     _mk("local-global", window=8, global_every=2),
+    _mk("window-beyond-cache", window=32),   # ring of max_len (28) slots
     _mk("moe", family="moe",
         moe=MoEConfig(n_experts=4, top_k=2, d_ff_expert=64,
                       capacity_factor=8.0)),   # high capacity: no drops
@@ -189,3 +214,17 @@ def test_ring_cache_decode_matches_forward_beyond_window():
     np.testing.assert_allclose(
         np.asarray(logits[:, 0], np.float32),
         np.asarray(full_logits[:, -1], np.float32), atol=3e-2, rtol=3e-2)
+
+
+def test_generate_twice_with_donated_cache():
+    """The decode step donates its cache: a second request with the same
+    prompt must serve the same tokens, so no donated buffer is read."""
+    from repro.launch.serve import Server
+    server = Server(_mk("donate"), max_len=24)
+    server.load()
+    prompt = {"tokens": np.asarray(jax.random.randint(
+        jax.random.PRNGKey(8), (2, 12), 0, 64), np.int32)}
+    first = server.generate(prompt, 8)
+    second = server.generate(prompt, 8)
+    assert first.shape == (2, 8)
+    np.testing.assert_array_equal(first, second)

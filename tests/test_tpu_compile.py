@@ -1,4 +1,5 @@
-"""Every Pallas kernel compiles for a TPU v5e at real widths.
+"""Every Pallas kernel, and the serving decode step, compiles for a TPU
+v5e at real widths.
 
 No chip is needed: the TPU compiler compiles for a described ``v5e:2x2``
 topology, and refuses what Mosaic would refuse on the chip (block shapes
@@ -9,6 +10,7 @@ tests and only the worker running this file loads the TPU library.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +22,8 @@ from repro.kernels.digest import block_digest
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.quantize import dequantize_int8, quantize_int8
 from repro.kernels.ssd_scan import ssd_scan_bhsd
+from repro.launch.serve import Server
+from repro.models import ModelConfig
 
 
 @pytest.fixture(scope="module")
@@ -103,3 +107,69 @@ def test_dequantize_int8_1m_floats(one_chip):
         functools.partial(dequantize_int8, shape=(1 << 20,)),
         _spec(one_chip, (4096, 256), jnp.int8),
         _spec(one_chip, (4096,), jnp.float32))
+
+
+# Phi-3-mini's cache widths (32 KV heads of 96, batch 12, 768 positions)
+# on two layers; the ring keeps 512 of the 768; and grouped heads of 128.
+DECODE_CASES = [
+    ModelConfig(name="full-hd96", family="dense", n_layers=2, d_model=3072,
+                n_heads=32, n_kv_heads=32, d_ff=512, vocab=512),
+    ModelConfig(name="ring-hd96", family="dense", n_layers=2, d_model=3072,
+                n_heads=32, n_kv_heads=32, d_ff=512, vocab=512, window=512),
+    ModelConfig(name="full-hd128-gqa", family="dense", n_layers=2,
+                d_model=1024, n_heads=16, n_kv_heads=8, head_dim=128,
+                d_ff=512, vocab=512),
+]
+
+
+def _unfused(text: str):
+    """(computation, opcode, shape, in HBM) of every instruction that no
+    fusion holds, fusions themselves included, from compiled HLO text; a
+    result in another memory space (``S(n)``, VMEM) is on-chip, a read."""
+    fused = set(re.findall(r"calls=%?([\w.\-]+)", text))
+    comp, out = None, []
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            comp = ("ENTRY" if head.group(1) else head.group(2))
+            continue
+        ins = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\](\S*) "
+                       r"([\w\-]+)\(", line)
+        if ins and comp not in fused:
+            out.append((comp, ins.group(3), tuple(
+                int(d) for d in ins.group(1).split(",") if d),
+                "S(" not in ins.group(2)))
+    return out
+
+
+@pytest.mark.parametrize("cfg", DECODE_CASES, ids=lambda c: c.name)
+def test_decode_step_writes_cache_in_place(one_chip, cfg):
+    """The served decode step donates its K/V cache and writes the step's
+    slot into it: no op, fused or not, materialises a layer of the cache
+    in HBM (a slice into VMEM is the attention's read), the whole cache is
+    written once after the layer scan, and the slot is contiguous in the
+    cache's layout."""
+    B, max_len = 12, 768
+    server = Server(cfg, max_len=max_len)
+    place = functools.partial(jax.tree.map, lambda a: _spec(
+        one_chip, a.shape, a.dtype))
+    params = place(jax.eval_shape(server.api.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: server.api.init_cache(B, max_len, server.ctx)))
+    compiled = server._decode.lower(
+        params, cache, _spec(one_chip, (B, 1), jnp.int32)).compile()
+
+    whole = cache["k"].shape
+    kv_bytes = 2 * cache["k"].size * cache["k"].dtype.itemsize
+    assert compiled.memory_analysis().alias_size_in_bytes >= kv_bytes
+    # with the position axis minor-most, one position's write would touch
+    # every tile of the cache
+    layout = compiled.input_formats[0][1]["k"].layout
+    assert layout.major_to_minor[-1] != 2, layout
+    layer = (whole[1:], (1,) + whole[1:])
+    writes = ("copy", "dynamic-slice", "dynamic-update-slice", "fusion")
+    for comp, op, shape, hbm in _unfused(compiled.as_text()):
+        assert not (hbm and shape in layer), (comp, op, shape)
+        # the one write of the step's K/V after the layer scan, in place
+        if shape == whole and op in writes:
+            assert comp == "ENTRY" and op != "copy", (comp, op)
