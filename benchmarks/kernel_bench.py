@@ -37,7 +37,7 @@ def run() -> None:
     Bm = jax.random.normal(jax.random.fold_in(k, 4), (Bs, 1, Ss, N))
     Cm = jax.random.normal(jax.random.fold_in(k, 5), (Bs, 1, Ss, N))
     t, y = time_it(lambda: jax.block_until_ready(
-        ssd_scan_bhsd(x, dt, A, Bm, Cm, chunk=64, interpret=True)))
+        ssd_scan_bhsd(x, dt, A, Bm, Cm, chunk=64, interpret=True)[0]))
     r = ref.ssd_scan_ref(x, dt, A, Bm, Cm, chunk=64)
     err = float(np.abs(np.asarray(y) - np.asarray(r)).max())
     emit("kernel/ssd_scan_interp", t * 1e6,

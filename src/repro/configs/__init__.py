@@ -1,6 +1,6 @@
 """Architecture registry: ``get_config("<arch-id>")`` / ``--arch <id>``.
 
-Ten assigned architectures (exact published dims, one module each) plus
+Eleven assigned architectures (exact published dims, one module each) plus
 the framework's own demo config.  ``get_smoke_config`` returns the
 reduced same-family variant used by CPU smoke tests.
 """
@@ -18,6 +18,7 @@ _REGISTRY: dict[str, str] = {
     "gemma3-1b": "gemma3_1b",
     "mistral-large-123b": "mistral_large_123b",
     "zamba2-1.2b": "zamba2_1_2b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
     "mixtral-8x22b": "mixtral_8x22b",
     "qwen3-moe-30b-a3b": "qwen3_moe_30b_a3b",
     "mamba2-1.3b": "mamba2_1_3b",

@@ -75,12 +75,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
-                                             "interpret"))
+                                             "interpret", "scale"))
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, window: int = 0,
                          bq: int = 256, bk: int = 256,
-                         interpret: bool = False) -> jax.Array:
-    """q: (B, Hq, Sq, hd); k/v: (B, Hkv, Sk, hd) -> (B, Hq, Sq, hd)."""
+                         interpret: bool = False,
+                         scale: float | None = None) -> jax.Array:
+    """q: (B, Hq, Sq, hd); k/v: (B, Hkv, Sk, hd) -> (B, Hq, Sq, hd); the
+    scores scaled by ``scale``, 1 / sqrt(hd) when None."""
     B, Hq, Sq, hd = q.shape
     _, Hkv, Sk, _ = k.shape
     assert Hq % Hkv == 0
@@ -89,7 +91,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bk = min(bk, Sk)
     assert Sq % bq == 0 and Sk % bk == 0, (Sq, bq, Sk, bk)
     nq, nk = Sq // bq, Sk // bk
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     kernel = functools.partial(_flash_kernel, bq=bq, bk=bk, nk=nk,
                                scale=scale, causal=causal, window=window)

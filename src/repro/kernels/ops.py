@@ -1,7 +1,8 @@
 """Jitted public wrappers for the Pallas kernels — the one backend seam.
 
-Model code calls these through ``ShardCtx.impl == "pallas"`` and the
-integrity layer calls :func:`block_digest`.  :func:`on_tpu` alone decides
+Model code calls these (attention through ``ShardCtx.impl == "pallas"``,
+a prefill's SSD on any TPU) and the integrity layer calls
+:func:`block_digest`.  :func:`on_tpu` alone decides
 how a kernel runs: compiled by Mosaic on a TPU backend, in interpret mode
 (kernel bodies run as Python over numpy, for correctness) on any other.
 Layout conversions between the model's (B, S, H, hd) convention and the
@@ -25,7 +26,8 @@ def on_tpu() -> bool:
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, window: int = 0) -> jax.Array:
+                    causal: bool = True, window: int = 0,
+                    scale: float | None = None) -> jax.Array:
     """(B, S, H, hd) layout in/out."""
     if q.shape[1] % 128 != 0 or k.shape[1] % 128 != 0:
         raise NotImplementedError("flash kernel needs seq % 128 == 0")
@@ -33,7 +35,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     ot = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                              interpret=not on_tpu())
+                              interpret=not on_tpu(), scale=scale)
     return jnp.swapaxes(ot, 1, 2)
 
 
@@ -50,15 +52,17 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
-             Cm: jax.Array, *, chunk: int = 256) -> jax.Array:
-    """Model layout: x (B, S, H, P); dt (B, S, H); Bm/Cm (B, S, G, N)."""
+             Cm: jax.Array, *, chunk: int = 256) -> tuple[jax.Array, jax.Array]:
+    """Model layout: x (B, S, H, P); dt (B, S, H); Bm/Cm (B, S, G, N) ->
+    (y (B, S, H, P), final state (B, H, P, N) f32)."""
     xt = jnp.moveaxis(x, 2, 1)
     dtt = jnp.moveaxis(dt, 2, 1)
     Bt = jnp.moveaxis(Bm, 2, 1)
     Ct = jnp.moveaxis(Cm, 2, 1)
-    y = ssd_scan_bhsd(xt, dtt.astype(jnp.float32), A.astype(jnp.float32),
-                      Bt, Ct, chunk=chunk, interpret=not on_tpu())
-    return jnp.moveaxis(y, 1, 2)
+    y, final = ssd_scan_bhsd(xt, dtt.astype(jnp.float32),
+                             A.astype(jnp.float32), Bt, Ct, chunk=chunk,
+                             interpret=not on_tpu())
+    return jnp.moveaxis(y, 1, 2), final
 
 
 def quantize(x: jax.Array, *, block: int = 256):

@@ -9,8 +9,10 @@ Per chunk the dual quadratic form runs on the MXU:
     y_inter = exp(cum_i) * (C_i @ state_in)
     state   = exp(total) * state_in + B^T @ (exp(total - cum) * dt * x)
 
-The pure-jnp oracle is :func:`repro.models.ssm.ssd_chunked`; tests sweep
-(B, S, H, P, N, chunk) in interpret mode.
+The state after the last chunk leaves as a second output, (B, H, P, N)
+f32: a prefill hands it to decode.  The pure-jnp oracle is
+:func:`repro.models.ssm.ssd_chunked`; tests sweep (B, S, H, P, N, chunk)
+in interpret mode.
 
 VMEM per step (Q=256, P=64, N<=128): x (Q,P) 64 KiB, B/C (Q,N) 128 KiB,
 L/CB (Q,Q) f32 256 KiB each, state (P,N) 32 KiB — well under budget.
@@ -28,8 +30,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
-                Q: int, nc: int):
+def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, final_ref,
+                state_ref, *, Q: int, nc: int):
     h = pl.program_id(1)
     ic = pl.program_id(2)
 
@@ -69,18 +71,24 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
     y = y + jnp.exp(cum_c) * jax.lax.dot_general(Cm, state, nt)
     # state update
     wdt = jnp.exp(total - cum_c) * dt_c                          # (Q, 1)
-    state_ref[...] = jnp.exp(total) * state + jax.lax.dot_general(
+    state = jnp.exp(total) * state + jax.lax.dot_general(
         x * wdt, Bm, (((0,), (0,)), ((), ())))                   # (P, N)
+    state_ref[...] = state
 
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
+
+    @pl.when(ic == nc - 1)
+    def _final():
+        final_ref[0, 0] = state
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd_scan_bhsd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                   Cm: jax.Array, *, chunk: int = 256,
-                  interpret: bool = False) -> jax.Array:
+                  interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """x: (B, H, S, P); dt: (B, H, S) f32; A: (H,) f32;
-    Bm/Cm: (B, G, S, N) (groups broadcast to heads) -> y (B, H, S, P)."""
+    Bm/Cm: (B, G, S, N) (groups broadcast to heads) -> (y (B, H, S, P),
+    final state (B, H, P, N) f32)."""
     B, H, S, P = x.shape
     G, N = Bm.shape[1], Bm.shape[3]
     assert H % G == 0
@@ -94,7 +102,7 @@ def ssd_scan_bhsd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     Cc = Cm.reshape(B, G, nc, chunk, N)
 
     kernel = functools.partial(_ssd_kernel, Q=chunk, nc=nc)
-    y = pl.pallas_call(
+    y, final = pl.pallas_call(
         kernel,
         grid=(B, H, nc),
         in_specs=[
@@ -104,10 +112,14 @@ def ssd_scan_bhsd(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
             pl.BlockSpec((1, 1, 1, chunk, N), lambda b, h, c: (b, h // rep, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, N), lambda b, h, c: (b, h // rep, c, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, chunk, P),
-                               lambda b, h, c: (b, h, c, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, nc, chunk, P), x.dtype),
+        out_specs=[
+            pl.BlockSpec((1, 1, 1, chunk, P), lambda b, h, c: (b, h, c, 0, 0)),
+            # one block per (b, h), resident across the chunks
+            pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((B, H, nc, chunk, P), x.dtype),
+                   jax.ShapeDtypeStruct((B, H, P, N), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
     )(xc, dtc, A, Bc, Cc)
-    return y.reshape(B, H, S, P)
+    return y.reshape(B, H, S, P), final

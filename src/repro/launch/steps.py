@@ -195,7 +195,7 @@ def cache_shardings(cache_abs: Any, mesh: Mesh) -> Any:
         if nd == 0:
             return NamedSharding(mesh, P())
         name = str(getattr(path[-1], "key", getattr(path[-1], "name", "")))
-        if nd == 5 or name in ("k", "v", "shared_k", "shared_v"):
+        if nd == 5 or name in ("k", "v", "attn_k", "attn_v"):
             return NamedSharding(mesh, kv_cache_spec(v.shape, dp, m, axes,
                                                      "model"))
         if nd == 4 and name in ("conv", ""):   # (L, B, W, C) conv state

@@ -172,8 +172,8 @@ class ModelApi:
         elif cfg.family == "ssm":
             n_ssd = cfg.n_layers
         elif cfg.family == "hybrid":
-            n_ssd = cfg.n_layers
-            n_attn = max(1, cfg.n_layers // max(cfg.attn_every, 1))
+            n_ssd = cfg.n_mamba
+            n_attn = cfg.attn_sites
         elif cfg.family == "encdec":
             n_attn = cfg.enc_layers + 2 * cfg.n_layers  # self + cross
 

@@ -55,7 +55,8 @@ def _build_mask(
 ATTN_CHUNK_ELEMS = 1 << 22
 
 
-def _attn_core(q, k, v, *, q_pos, k_pos, causal, window) -> jax.Array:
+def _attn_core(q, k, v, *, q_pos, k_pos, causal, window, scale=None
+               ) -> jax.Array:
     # named_scope tags every op in here as belonging to a region a fused
     # flash-attention kernel replaces on TPU: core/fidelity.py separates
     # these bytes so the roofline can report raw vs. kernel-fused memory
@@ -67,7 +68,7 @@ def _attn_core(q, k, v, *, q_pos, k_pos, causal, window) -> jax.Array:
         assert Hq % Hkv == 0, (Hq, Hkv)
         G = Hq // Hkv
         qg = q.reshape(B, Sq, Hkv, G, hd)
-        scale = hd ** -0.5
+        scale = hd ** -0.5 if scale is None else scale
         # mixed-precision dot: bf16 operands, f32 accumulation — native on
         # the TPU MXU (avoids materializing f32 casts of the K cache)
         scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k,
@@ -93,8 +94,10 @@ def attention(
     window: int | jax.Array = 0,
     impl: str = "ref",
     q_chunk: int | None = None,
+    scale: float | None = None,
 ) -> jax.Array:
-    """Grouped-query attention; returns (B, Sq, Hq, hd).
+    """Grouped-query attention; returns (B, Sq, Hq, hd).  ``scale``
+    multiplies the scores: None is 1 / sqrt(hd).
 
     q_chunk: None = auto (chunk when the score workspace is large),
     0 = never chunk (caller bounds memory another way, e.g. sequence-
@@ -102,14 +105,15 @@ def attention(
     """
     if impl == "pallas":
         out = _try_pallas(q, k, v, q_pos=q_pos, k_pos=k_pos, causal=causal,
-                          window=window)
+                          window=window, scale=scale)
         if out is not None:
             return out
     B, Sq, Hq, hd = q.shape
     Sk = k.shape[1]
-    if (q_chunk == 0 or Sq * Sk <= ATTN_CHUNK_ELEMS or q_pos.ndim != 1):
+    if (q_chunk == 0 or q_pos.ndim != 1
+            or (q_chunk is None and Sq * Sk <= ATTN_CHUNK_ELEMS)):
         return _attn_core(q, k, v, q_pos=q_pos, k_pos=k_pos, causal=causal,
-                          window=window)
+                          window=window, scale=scale)
     # query-chunked evaluation: scan over Sq blocks; the body is
     # checkpointed so backward recomputes each block's scores instead of
     # saving the full (Sq, Sk) probability tensor.
@@ -125,7 +129,7 @@ def attention(
     def body(_, inp):
         qi, qpi = inp
         return None, _attn_core(qi, k, v, q_pos=qpi, k_pos=k_pos,
-                                causal=causal, window=window)
+                                causal=causal, window=window, scale=scale)
 
     _, outs = jax.lax.scan(body, None, (qc, qpc))
     return jnp.moveaxis(outs, 0, 1).reshape(B, Sq, Hq, hd)
@@ -141,6 +145,7 @@ def attention_decode(
     q_pos: jax.Array,        # (1,)
     k_pos: jax.Array,        # (Sk,) cache slot positions, -1 invalid
     window: int | jax.Array = 0,
+    scale: float | None = None,
 ) -> jax.Array:
     """Single-query decode attention over a cache it only reads, with the
     step's own key and value as one more column of the same softmax;
@@ -167,7 +172,7 @@ def attention_decode(
                           ).reshape(B, Hkv * hd, Hq)
         k_new = k_new.reshape(B, Hkv, hd).astype(k_cache.dtype)
         v_new = v_new.reshape(B, Hkv, hd).astype(v_cache.dtype)
-        scale = hd ** -0.5
+        scale = hd ** -0.5 if scale is None else scale
         s_cache = jnp.einsum("bsc,bcj->bjs", k_cache, q_bd,
                              preferred_element_type=jnp.float32) * scale
         mask = _build_mask(q_pos, k_pos, causal=True, window=window)
@@ -191,7 +196,8 @@ def attention_decode(
         return out.astype(q.dtype).reshape(B, 1, Hq, hd)
 
 
-def _try_pallas(q, k, v, *, q_pos, k_pos, causal, window) -> Optional[jax.Array]:
+def _try_pallas(q, k, v, *, q_pos, k_pos, causal, window, scale
+                ) -> Optional[jax.Array]:
     """Route to the Pallas flash kernel when the shape regime fits it
     (train/prefill: Sq == Sk a multiple of 128, a static window); any
     other regime takes the reference.  A kernel error propagates."""
@@ -200,7 +206,8 @@ def _try_pallas(q, k, v, *, q_pos, k_pos, causal, window) -> Optional[jax.Array]
     if isinstance(window, jax.Array):
         return None                  # per-layer window traced through scan
     from repro.kernels import ops as kops
-    return kops.flash_attention(q, k, v, causal=causal, window=int(window))
+    return kops.flash_attention(q, k, v, causal=causal, window=int(window),
+                                scale=scale)
 
 
 # ---------------------------------------------------------------------------

@@ -226,15 +226,19 @@ def self_attention_block(
     q_pos: jax.Array, k_pos: jax.Array,
     k_cached: jax.Array | None = None, v_cached: jax.Array | None = None,
     causal: bool = True, window: int | jax.Array = 0,
+    q_chunk: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """QKV projections + RoPE + attention.  Returns (out, k_new, v_new)
-    where k_new/v_new are this step's keys/values (pre-cache, post-RoPE)."""
+    """QKV projections + RoPE (unless ``cfg.rope`` is off) + attention.
+    Returns (out, k_new, v_new) where k_new/v_new are this step's
+    keys/values (pre-cache, post-RoPE).  ``q_chunk`` as in ``attention``;
+    None leaves the choice to the sharding and the score size."""
     B, S, D = x.shape
     q = jnp.einsum("bsd,dq->bsq", x, p["wq"]).reshape(B, S, cfg.n_heads, cfg.hd)
     k = jnp.einsum("bsd,dk->bsk", x, p["wk"]).reshape(B, S, cfg.n_kv_heads, cfg.hd)
     v = jnp.einsum("bsd,dk->bsk", x, p["wv"]).reshape(B, S, cfg.n_kv_heads, cfg.hd)
-    q = apply_rope(q, q_pos, cfg.rope_theta)
-    k = apply_rope(k, q_pos, cfg.rope_theta)   # new keys carry current positions
+    if cfg.rope:
+        q = apply_rope(q, q_pos, cfg.rope_theta)
+        k = apply_rope(k, q_pos, cfg.rope_theta)   # new keys carry current positions
     q = ctx.shard_heads(q, role="q")
     # GQA sharding repair: when Hq shards over the model axis but Hkv does
     # not (kv=8 on a 16-wide axis), the (Hkv, G) grouping reshape breaks
@@ -264,11 +268,12 @@ def self_attention_block(
     # sharding transitions trigger involuntary full rematerialization in
     # GSPMD (measured: 4.2 TiB/dev of score all-gathers on
     # mistral-large train_4k — EXPERIMENTS.md §Perf iteration M1)
-    q_chunk = 0 if (ctx.heads_shardable(cfg.n_heads)
-                    or ctx.seq_parallel_attn(cfg.n_heads, S)) else None
+    if q_chunk is None and (ctx.heads_shardable(cfg.n_heads)
+                            or ctx.seq_parallel_attn(cfg.n_heads, S)):
+        q_chunk = 0
     out = attention(q, k_all, v_all, q_pos=q_pos, k_pos=k_pos,
                     causal=causal, window=window, impl=ctx.impl,
-                    q_chunk=q_chunk)
+                    q_chunk=q_chunk, scale=cfg.attention_multiplier)
     out = out.reshape(B, S, cfg.q_dim)
     return jnp.einsum("bsq,qd->bsd", out, p["wo"]), k, v
 

@@ -1,7 +1,8 @@
 """Model configuration — one dataclass covering every assigned family.
 
 Families: ``dense`` (GQA transformer), ``moe`` (sparse FFN), ``ssm``
-(Mamba2/SSD), ``hybrid`` (Mamba2 + shared attention block, Zamba2-style),
+(Mamba2/SSD), ``hybrid`` (Mamba2 + attention: Zamba2's shared block every
+``attn_every`` layers, or Granite-4.0-H's per-layer ``layer_types``),
 ``encdec`` (encoder-decoder, Seamless-style), ``vlm`` (dense backbone +
 patch-embedding frontend stub).
 
@@ -55,16 +56,33 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     attn_every: int = 0                        # hybrid: shared attn block every k layers
+    # hybrid: each layer's mixer, "mamba" or "attention", each with its own
+    # weights and an MLP after every mixer (Granite-4.0-H); empty: Zamba2
+    layer_types: tuple[str, ...] = ()
     enc_layers: int = 0                        # encdec: encoder depth
     frontend: Optional[str] = None             # None | "patch" | "frames"
     frontend_len: int = 576                    # stub embedding length
     # numerics / misc
     rope_theta: float = 10000.0
+    rope: bool = True                          # False: no position embedding
+    # Granite's multipliers; the defaults change nothing
+    embedding_multiplier: float = 1.0          # h0 = m * E[tok]
+    residual_multiplier: float = 1.0           # h + m * f(h) (hybrid)
+    attention_multiplier: Optional[float] = None   # softmax scale; None: hd ** -0.5
+    logits_scaling: float = 1.0                # logits = norm(h) @ head / s
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     remat: str = "full"                        # none | dots | full
     max_seq_len: int = 131072
     source: str = ""                           # provenance note
+
+    def __post_init__(self):
+        # a configuration read from JSON: lists and nested groups as given
+        if isinstance(self.ssm, dict):
+            object.__setattr__(self, "ssm", SSMConfig(**self.ssm))
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
 
     # ------------------------------------------------------------------
     @property
@@ -109,10 +127,59 @@ class ModelConfig:
         if self.family == "ssm":
             return ["mamba"] * self.n_layers
         if self.family == "hybrid":
-            return ["mamba"] * self.n_layers  # shared attn woven in separately
+            return ["mamba"] * self.n_mamba   # attention woven in separately
         if self.family == "encdec":
             return ["attn"] * self.n_layers
         raise ValueError(self.family)
+
+    def hybrid_schedule(self) -> list[tuple[str, int, int]]:
+        """A hybrid stack in execution order: ``("mamba", lo, hi)`` runs
+        the stacked Mamba layers lo..hi-1, ``("attn", w, site)`` the
+        attention layer of weight set ``w`` with the cache of ``site``.
+        Zamba2 has one weight set, applied after every ``attn_every``
+        Mamba layers and after the last; Granite one per attention layer;
+        the ssm family none."""
+        if self.family == "ssm":
+            return [("mamba", 0, self.n_layers)]
+        if self.layer_types:
+            kinds = list(self.layer_types)
+        else:
+            every = self.attn_every or self.n_layers
+            kinds = []
+            for i in range(self.n_layers):
+                kinds.append("mamba")
+                if (i + 1) % every == 0 or i + 1 == self.n_layers:
+                    kinds.append("attention")
+        out: list[tuple[str, int, int]] = []
+        m = sites = 0
+        for kind in kinds:
+            if kind == "mamba":
+                if out and out[-1][0] == "mamba":
+                    out[-1] = ("mamba", out[-1][1], m + 1)
+                else:
+                    out.append(("mamba", m, m + 1))
+                m += 1
+            else:
+                out.append(("attn", sites if self.layer_types else 0, sites))
+                sites += 1
+        return out
+
+    @property
+    def n_mamba(self) -> int:
+        """Mamba layers (their stacked weights and states)."""
+        if self.family == "hybrid" and self.layer_types:
+            return self.layer_types.count("mamba")
+        return self.n_layers
+
+    @property
+    def attn_sites(self) -> int:
+        """A hybrid's attention applications, each with its own cache."""
+        return sum(k == "attn" for k, _, _ in self.hybrid_schedule())
+
+    @property
+    def attn_weight_sets(self) -> int:
+        """A hybrid's attention weight sets: Zamba2 shares one."""
+        return self.attn_sites if self.layer_types else 1
 
     def layer_windows(self) -> list[int]:
         """Per-layer attention window (0 = full).  Implements the paper
@@ -141,8 +208,10 @@ class ModelConfig:
         elif self.family == "ssm":
             total += self.n_layers * (per_mamba + 2 * D)
         elif self.family == "hybrid":
-            total += self.n_layers * (per_mamba + 2 * D)
-            total += per_attn + per_mlp + 2 * D  # one shared block
+            # Granite: an MLP after every mixer; Zamba2: none after Mamba
+            per_m = per_mamba + D + ((per_mlp + D) if self.layer_types else 0)
+            total += self.n_mamba * per_m
+            total += self.attn_weight_sets * (per_attn + per_mlp + 2 * D)
         elif self.family == "encdec":
             # encoder self-attn+mlp, decoder self+cross+mlp
             total += self.enc_layers * (per_attn + per_mlp + 2 * D)
@@ -162,7 +231,7 @@ class ModelConfig:
 
     def validate(self) -> None:
         assert self.family in ("dense", "moe", "ssm", "hybrid", "encdec", "vlm"), self.family
-        if self.family not in ("ssm", "hybrid"):
+        if self.family != "ssm":
             assert self.n_heads >= 1 and self.n_kv_heads >= 1
             assert self.n_heads % self.n_kv_heads == 0, "GQA group must divide"
         if self.family == "moe":
@@ -170,6 +239,12 @@ class ModelConfig:
         if self.family in ("ssm", "hybrid"):
             assert self.ssm is not None
             assert self.d_inner % (self.ssm.head_dim) == 0
+        if self.layer_types:
+            assert self.family == "hybrid", "layer_types is a hybrid's"
+            assert len(self.layer_types) == self.n_layers
+            assert set(self.layer_types) <= {"mamba", "attention"}
+        if self.family != "hybrid":
+            assert self.residual_multiplier == 1.0, "hybrid only"
         if self.family == "encdec":
             assert self.enc_layers > 0
 
@@ -203,7 +278,12 @@ def smoke_variant(cfg: ModelConfig, **overrides) -> ModelConfig:
     if cfg.ssm:
         small["ssm"] = SSMConfig(d_state=16, head_dim=16, expand=2,
                                  n_groups=1, conv_width=4, chunk=16)
-    if cfg.family == "hybrid":
+    if cfg.family == "hybrid" and cfg.layer_types:
+        # attention at uneven positions, as in the published pattern
+        small["layer_types"] = ("mamba", "attention", "mamba", "mamba",
+                                "attention")
+        small["n_layers"] = 5
+    elif cfg.family == "hybrid":
         small["attn_every"] = 2
     if cfg.family == "encdec":
         small["enc_layers"] = 2

@@ -6,9 +6,13 @@ Heterogeneity is handled without breaking the scan:
 
 * per-layer attention windows (gemma3's 5:1 local:global) ride through the
   scan as an ``int32`` xs array feeding the mask,
-* zamba2's shared attention block (one set of weights applied every
-  ``attn_every`` layers) splits the Mamba stack into segments, scanning
-  each segment and applying the shared block between segments,
+* a hybrid (``ModelConfig.hybrid_schedule``: zamba2's one shared
+  attention block every ``attn_every`` layers, granite's attention layers
+  at uneven positions, each with its own weights) splits the Mamba stack
+  into runs, scanning each run and applying attention between them; the
+  ssm family is a hybrid with no attention.  Mamba states, stacked over
+  the Mamba layers, ride through those scans as a carry that each layer
+  updates in place,
 * decode caches enter the scan as read-only xs; each layer's new K/V
   leaves as a small ys and is written into the stacked cache once after
   the scan (in place where the caller donates the cache), keeping
@@ -31,12 +35,12 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from . import ffn as ffn_lib
 from . import ssm as ssm_lib
 from .attention import (attention_decode, cache_positions_full,
                         cache_positions_ring)
-from .blocks import (ShardCtx, dense_layer_apply, init_dense_layer,
-                     init_mamba_layer, init_moe_layer, moe_layer_apply,
-                     stack_layers)
+from .blocks import (ShardCtx, dense_layer_apply, init_mlp_params,
+                     moe_layer_apply, self_attention_block, stack_layers)
 from .common import (apply_rope, cross_entropy_loss, dense_init, embed_init,
                      rms_norm)
 from .config import ModelConfig
@@ -63,13 +67,18 @@ def init_lm(cfg: ModelConfig, key: jax.Array) -> dict:
     params: dict[str, Any] = {"embed": embed_init(keys[0], (V, D))}
     kind = {"dense": "attn", "vlm": "attn", "moe": "moe",
             "ssm": "mamba", "hybrid": "mamba"}[cfg.family]
-    params["layers"] = stack_layers(keys[1], cfg, cfg.n_layers, kind)
-    if cfg.family == "hybrid":
-        shared = init_dense_layer(keys[2], cfg)
-        params["shared_attn"] = shared
+    params["layers"] = stack_layers(keys[1], cfg, cfg.n_mamba, kind)
     if cfg.family in ("ssm", "hybrid"):
         # mamba layers need a pre-norm scale
-        params["layers"]["ln"] = jnp.zeros((cfg.n_layers, D), jnp.float32)
+        params["layers"]["ln"] = jnp.zeros((cfg.n_mamba, D), jnp.float32)
+    if cfg.family == "hybrid":
+        params["attn_layers"] = stack_layers(keys[2], cfg,
+                                             cfg.attn_weight_sets, "attn")
+        if cfg.layer_types:            # an MLP after every mixer
+            params["layers"]["mlp"] = jax.vmap(
+                lambda k: init_mlp_params(k, cfg))(
+                    jax.random.split(keys[6], cfg.n_mamba))
+            params["layers"]["ln2"] = jnp.zeros((cfg.n_mamba, D), jnp.float32)
     if cfg.frontend:
         params["projector"] = {
             "w1": dense_init(keys[3], (D, D), D),
@@ -86,9 +95,16 @@ def init_lm(cfg: ModelConfig, key: jax.Array) -> dict:
 # ---------------------------------------------------------------------------
 
 
+def _embed(params: dict, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
+    x = params["embed"][tokens]
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
 def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jax.Array,
                   ctx: ShardCtx, extra_embeds: Optional[jax.Array]) -> jax.Array:
-    x = params["embed"][tokens]
+    x = _embed(params, cfg, tokens)
     if cfg.frontend:
         assert extra_embeds is not None, "frontend arch needs stub embeddings"
         fe = extra_embeds.astype(x.dtype)
@@ -102,14 +118,38 @@ def _embed_inputs(params: dict, cfg: ModelConfig, tokens: jax.Array,
 def _logits(params: dict, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return jnp.einsum("bsd,dv->bsv", x, head)
+    logits = jnp.einsum("bsd,dv->bsv", x, head)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
-def _mamba_layer_apply(x, lp, cfg, ctx):
+def _add(x, y, cfg):
+    """The residual add, its branch scaled by ``residual_multiplier``."""
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
+def _mlp_residual(x, lp, cfg, ctx):
+    """x + MLP(RMSNorm(x)): the second half of a layer."""
+    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+    y = ffn_lib.swiglu(h, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
+                       lp["mlp"]["w_down"])
+    return ctx.shard_act(_add(x, y, cfg))
+
+
+def _mamba_layer_apply(x, lp, cfg, ctx, return_state: bool = False):
+    """A Mamba layer: x + Mamba2(RMSNorm(x)), then an MLP where the layer
+    has one; with ``return_state`` also the layer's MambaState."""
     h = rms_norm(x, lp["ln"], cfg.norm_eps)
-    y = ssm_lib.mamba_block_train(h, lp, cfg, impl=ctx.impl,
-                                  shard_heads=ctx.shard_heads)
-    return ctx.shard_act(x + y)
+    out = ssm_lib.mamba_block_train(h, lp, cfg, shard_heads=ctx.shard_heads,
+                                    return_state=return_state)
+    y, st = out if return_state else (out, None)
+    x = ctx.shard_act(_add(x, y, cfg))
+    if "mlp" in lp:
+        x = _mlp_residual(x, lp, cfg, ctx)
+    return (x, st) if return_state else x
 
 
 def _scan_stack(x, layers, cfg, ctx, positions, windows, body_kind,
@@ -129,13 +169,7 @@ def _scan_stack(x, layers, cfg, ctx, positions, windows, body_kind,
                                      window=w)
         return (h, lb + lbi, z + zi), None
 
-    def mamba_body(carry, xs):
-        h, lb, z = carry
-        lp, w = xs
-        h = _mamba_layer_apply(h, lp, cfg, ctx)
-        return (h, lb, z), None
-
-    body = {"attn": dense_body, "moe": moe_body, "mamba": mamba_body}[body_kind]
+    body = {"attn": dense_body, "moe": moe_body}[body_kind]
     body = _remat(body, cfg.remat)
     zero = jnp.zeros((), jnp.float32)
     (x, lb, z), _ = jax.lax.scan(body, (x, zero, zero), (layers, windows))
@@ -157,44 +191,53 @@ def forward_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
     elif cfg.family == "moe":
         x, lb, z = _scan_stack(x, params["layers"], cfg, ctx, positions,
                                windows, "moe")
-    elif cfg.family == "ssm":
-        x, lb, z = _scan_stack(x, params["layers"], cfg, ctx, positions,
-                               windows, "mamba")
-    elif cfg.family == "hybrid":
-        x, lb, z = _hybrid_forward(params, cfg, x, ctx, positions, windows)
+    elif cfg.family in ("ssm", "hybrid"):
+        x = _hybrid_forward(params, cfg, x, ctx, positions)
+        lb = z = jnp.zeros((), jnp.float32)
     else:
         raise ValueError(cfg.family)
     return _logits(params, cfg, x), lb, z
 
 
-def _segment_bounds(n_layers: int, every: int) -> list[tuple[int, int]]:
-    bounds, start = [], 0
-    while start < n_layers:
-        bounds.append((start, min(start + every, n_layers)))
-        start += every
-    return bounds
+def _layer(layers: dict, i) -> dict:
+    """Layer ``i`` (static or traced) of stacked layer parameters."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), layers)
 
 
-def _slice_layers(layers: dict, lo: int, hi: int) -> dict:
-    return jax.tree.map(lambda a: jax.lax.slice_in_dim(a, lo, hi, axis=0),
-                        layers)
+def _scan_layers(body, carry, layers: dict, lo: int, hi: int):
+    """``carry = body(carry, layer lo's params, lo)`` ... through hi - 1,
+    as one scan over the layer index: each layer's parameters are read out
+    of the whole stack in the loop, where a static slice of the stack
+    would be copied once per run of layers."""
+    def step(c, l):
+        return body(c, _layer(layers, l), l), None
+    return jax.lax.scan(step, carry, jnp.arange(lo, hi, dtype=jnp.int32))[0]
 
 
-def _hybrid_forward(params, cfg, x, ctx, positions, windows):
-    """Zamba2 pattern: Mamba segments with a shared attention block between
-    (same weights at every application site)."""
-    zero = jnp.zeros((), jnp.float32)
-    lb = z = zero
-    shared_window = cfg.window  # 0 (full) normally; ring window for long ctx
-    for lo, hi in _segment_bounds(cfg.n_layers, cfg.attn_every or cfg.n_layers):
-        seg = _slice_layers(params["layers"], lo, hi)
-        x, lbi, zi = _scan_stack(x, seg, cfg, ctx, positions,
-                                 windows[lo:hi], "mamba")
-        lb, z = lb + lbi, z + zi
-        if hi < cfg.n_layers or hi == cfg.n_layers:
-            x = dense_layer_apply(x, params["shared_attn"], cfg, ctx,
-                                  positions=positions, window=shared_window)
-    return x, lb, z
+def _attn_site(x, lp, cfg, ctx, positions, q_chunk=None):
+    """A hybrid's attention layer over a whole sequence: attention, then
+    its MLP.  Returns (x, k, v), the keys and values (B, S, Hkv, hd)."""
+    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+    out, k, v = self_attention_block(
+        h, lp["attn"], cfg, ctx, q_pos=positions, k_pos=positions,
+        causal=True, window=cfg.window, q_chunk=q_chunk)
+    x = ctx.shard_act(_add(x, out, cfg))
+    return _mlp_residual(x, lp, cfg, ctx), k, v
+
+
+def _hybrid_forward(params, cfg, x, ctx, positions):
+    """The ssm and hybrid stacks: runs of Mamba layers with attention
+    layers between them, in ``cfg.hybrid_schedule()`` order."""
+    body = _remat(lambda h, lp, _: _mamba_layer_apply(h, lp, cfg, ctx),
+                  cfg.remat)
+    for kind, a, b in cfg.hybrid_schedule():
+        if kind == "mamba":
+            x = _scan_layers(body, x, params["layers"], a, b)
+        else:
+            x, _, _ = _attn_site(x, _layer(params["attn_layers"], a), cfg,
+                                 ctx, positions)
+    return x
 
 
 # ---------------------------------------------------------------------------
@@ -236,13 +279,11 @@ def prefill_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
             h = carry
             lp, w = xs
             hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
-            from .blocks import self_attention_block
             attn_out, k_new, v_new = self_attention_block(
                 hn, lp["attn"], cfg, ctx, q_pos=positions, k_pos=positions,
                 causal=True, window=w)
             h = ctx.shard_act(h + attn_out)
             h2 = rms_norm(h, lp["ln2"], cfg.norm_eps)
-            from . import ffn as ffn_lib
             if is_moe:
                 moe_p = lp["moe"]
                 impl = ctx.choose_moe(cfg)
@@ -284,23 +325,9 @@ def prefill_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
         x, (k_all, v_all) = jax.lax.scan(body, x, (params["layers"], windows))
         cache["k"], cache["v"] = k_all, v_all
 
-    elif cfg.family == "ssm":
-        def body(h, xs):
-            lp, w = xs
-            hn = rms_norm(h, lp["ln"], cfg.norm_eps)
-            y, st = ssm_lib.mamba_block_train(
-                hn, lp, cfg, impl=ctx.impl, shard_heads=ctx.shard_heads,
-                return_state=True)
-            return ctx.shard_act(h + y), (st.conv, st.ssm)
-
-        body = _remat(body, cfg.remat)
-        x, (conv_all, ssm_all) = jax.lax.scan(body, x,
-                                              (params["layers"], windows))
-        cache["mamba"] = ssm_lib.MambaState(conv=conv_all, ssm=ssm_all)
-
-    elif cfg.family == "hybrid":
-        x, cache = _hybrid_prefill(params, cfg, x, ctx, positions, windows,
-                                   cache, s_cache)
+    elif cfg.family in ("ssm", "hybrid"):
+        x, cache = _hybrid_prefill(params, cfg, x, ctx, positions, cache,
+                                   s_cache)
     else:
         raise ValueError(cfg.family)
 
@@ -309,51 +336,117 @@ def prefill_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
     return logits, cache
 
 
-def _hybrid_prefill(params, cfg, x, ctx, positions, windows, cache, s_cache):
-    from .blocks import self_attention_block
-    from . import ffn as ffn_lib
+#: a hybrid attention layer's prefill scores are evaluated in query chunks
+#: of at most this many f32 elements (B * Hq * chunk * S; 1 GiB)
+SITE_SCORE_ELEMS = 1 << 28
+
+
+def _site_q_chunk(batch: int, cfg: ModelConfig, seq: int) -> Optional[int]:
+    """Query chunk for a hybrid attention layer's prefill: None (the
+    attention's own choice) while the whole score tensor is within
+    ``SITE_SCORE_ELEMS``, else the largest halving of the sequence that
+    is, or 128."""
+    per_query = batch * cfg.n_heads * seq
+    if per_query * seq <= SITE_SCORE_ELEMS:
+        return None
+    chunk = seq
+    while chunk > 128 and chunk % 2 == 0 and per_query * chunk > SITE_SCORE_ELEMS:
+        chunk //= 2
+    return chunk
+
+
+def _pack_kv(kv: jax.Array, ring: bool, s_cache: int) -> jax.Array:
+    """A prefill's (B, S, Hkv, hd) keys or values as cache rows
+    (B, s_cache, Hkv * hd): ring slots in a ring, else padded."""
+    B, S = kv.shape[:2]
+    kv = kv.reshape(B, S, -1)
+    if ring:
+        return _ring_pack(kv, s_cache).astype(jnp.bfloat16)
+    return jnp.pad(kv, ((0, 0), (0, s_cache - S), (0, 0))).astype(jnp.bfloat16)
+
+
+def _state_at(st: "ssm_lib.MambaState", l) -> "ssm_lib.MambaState":
+    """Layer ``l``'s state out of the stacked states."""
+    return ssm_lib.MambaState(
+        conv=jax.lax.dynamic_index_in_dim(st.conv, l, keepdims=False),
+        ssm=jax.lax.dynamic_index_in_dim(st.ssm, l, keepdims=False))
+
+
+def _state_put(st: "ssm_lib.MambaState", new: "ssm_lib.MambaState", l
+               ) -> "ssm_lib.MambaState":
+    """The stacked states with layer ``l``'s replaced, in place."""
+    return ssm_lib.MambaState(
+        conv=jax.lax.dynamic_update_index_in_dim(
+            st.conv, new.conv.astype(st.conv.dtype), l, 0),
+        ssm=jax.lax.dynamic_update_index_in_dim(
+            st.ssm, new.ssm.astype(st.ssm.dtype), l, 0))
+
+
+#: a hybrid prefill runs its batch in pieces of at most this many tokens.
+#: Compiled for a TPU v5e, granite-4.0-h-micro's prefill of 32 x 2048
+#: tokens holds 9.6 GB of temporaries whole (19.1 GB live with weights and
+#: cache), and 4.9, 3.5 and 2.7 GB in pieces of 16384, 8192 and 4096
+PREFILL_TOKENS = 8192
+
+
+def _hybrid_prefill(params, cfg, x, ctx, positions, cache, s_cache):
+    """``_hybrid_forward`` that also fills the cache, in pieces of the
+    batch of at most ``PREFILL_TOKENS`` tokens each.  Returns (the last
+    position's hidden state (B, 1, D), cache)."""
     B, S = x.shape[:2]
-    conv_out, ssm_out, k_sites, v_sites = [], [], [], []
+    rows = B
+    while rows > 1 and rows * S > PREFILL_TOKENS and rows % 2 == 0:
+        rows //= 2
+    if rows == B:
+        x, cache = _hybrid_prefill_rows(params, cfg, x, ctx, positions,
+                                        cache, s_cache)
+        return x[:, -1:], cache
 
-    def seg_body(h, xs):
-        lp, w = xs
-        hn = rms_norm(h, lp["ln"], cfg.norm_eps)
-        y, st = ssm_lib.mamba_block_train(
-            hn, lp, cfg, impl=ctx.impl, shard_heads=ctx.shard_heads,
-            return_state=True)
-        return ctx.shard_act(h + y), (st.conv, st.ssm)
+    def piece(cache, i):
+        at = i * rows
+        part = jax.tree.map(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, at, rows, 1)
+            if a.ndim else a, cache)
+        h, part = _hybrid_prefill_rows(
+            params, cfg, jax.lax.dynamic_slice_in_dim(x, at, rows, 0), ctx,
+            positions, part, s_cache)
+        cache = jax.tree.map(
+            lambda a, p: jax.lax.dynamic_update_slice_in_dim(a, p, at, 1)
+            if a.ndim else a, cache, part)
+        return cache, h[:, -1:]
 
-    seg_body = _remat(seg_body, cfg.remat)
-    for lo, hi in _segment_bounds(cfg.n_layers, cfg.attn_every or cfg.n_layers):
-        seg = _slice_layers(params["layers"], lo, hi)
-        x, (conv_n, ssm_n) = jax.lax.scan(seg_body, x, (seg, windows[lo:hi]))
-        conv_out.append(conv_n)
-        ssm_out.append(ssm_n)
-        sp = params["shared_attn"]
-        hn = rms_norm(x, sp["ln1"], cfg.norm_eps)
-        attn_out, k_new, v_new = self_attention_block(
-            hn, sp["attn"], cfg, ctx, q_pos=positions, k_pos=positions,
-            causal=True, window=cfg.window)
-        x = ctx.shard_act(x + attn_out)
-        h2 = rms_norm(x, sp["ln2"], cfg.norm_eps)
-        x = ctx.shard_act(x + ffn_lib.swiglu(h2, sp["mlp"]["w_gate"],
-                                             sp["mlp"]["w_up"],
-                                             sp["mlp"]["w_down"]))
-        k_new = k_new.reshape(B, S, cfg.kv_dim)
-        v_new = v_new.reshape(B, S, cfg.kv_dim)
-        if cfg.window > 0:
-            k_sites.append(_ring_pack(k_new, s_cache).astype(jnp.bfloat16))
-            v_sites.append(_ring_pack(v_new, s_cache).astype(jnp.bfloat16))
+    cache, last = jax.lax.scan(piece, cache, jnp.arange(B // rows))
+    return last.reshape((B,) + last.shape[2:]), cache
+
+
+def _hybrid_prefill_rows(params, cfg, x, ctx, positions, cache, s_cache):
+    """``_hybrid_forward`` that also fills the cache: each Mamba layer's
+    final state into the stacked states, each attention site's keys and
+    values into its own cache."""
+    B, S = x.shape[:2]
+    ring = cache_kind(cfg) == "ring"
+    q_chunk = _site_q_chunk(B, cfg, S)
+    k_sites, v_sites = [], []
+
+    def body(carry, lp, l):
+        h, states = carry
+        h, st = _mamba_layer_apply(h, lp, cfg, ctx, return_state=True)
+        return h, _state_put(states, st, l)
+
+    body = _remat(body, cfg.remat)
+    states = cache["mamba"]
+    for kind, a, b in cfg.hybrid_schedule():
+        if kind == "mamba":
+            x, states = _scan_layers(body, (x, states), params["layers"], a, b)
         else:
-            pad = [(0, 0)] * 3
-            pad[1] = (0, cache["shared_k"].shape[2] - S)
-            k_sites.append(jnp.pad(k_new, pad).astype(jnp.bfloat16))
-            v_sites.append(jnp.pad(v_new, pad).astype(jnp.bfloat16))
-
-    cache["mamba"] = ssm_lib.MambaState(conv=jnp.concatenate(conv_out, 0),
-                                        ssm=jnp.concatenate(ssm_out, 0))
-    cache["shared_k"] = jnp.stack(k_sites)
-    cache["shared_v"] = jnp.stack(v_sites)
+            x, k, v = _attn_site(x, _layer(params["attn_layers"], a), cfg,
+                                 ctx, positions, q_chunk)
+            k_sites.append(_pack_kv(k, ring, s_cache))
+            v_sites.append(_pack_kv(v, ring, s_cache))
+    cache["mamba"] = states
+    if k_sites:
+        cache["attn_k"] = ctx.shard_kv_cache(jnp.stack(k_sites))
+        cache["attn_v"] = ctx.shard_kv_cache(jnp.stack(v_sites))
     return x, cache
 
 
@@ -400,25 +493,25 @@ def init_lm_cache(cfg: ModelConfig, batch: int, max_len: int,
     """Decode cache pytree.  Shapes are static; `pos` tracks the clock."""
     ctx = ctx or ShardCtx()
     cache: dict[str, Any] = {"pos": jnp.zeros((), jnp.int32)}
-    L = cfg.n_layers
+    s = _attn_cache_len(cfg, max_len)
     if cfg.family in ("dense", "vlm", "moe"):
-        s = _attn_cache_len(cfg, max_len)
-        kv = jnp.zeros((L, batch, s, cfg.kv_dim), jnp.bfloat16)
+        kv = jnp.zeros((cfg.n_layers, batch, s, cfg.kv_dim), jnp.bfloat16)
         cache["k"] = ctx.shard_kv_cache(kv)
         cache["v"] = ctx.shard_kv_cache(kv)
     elif cfg.family in ("ssm", "hybrid"):
+        # two kinds of state side by side: every Mamba layer's conv and
+        # SSM state, and the K/V of every attention site
         st = ssm_lib.init_mamba_state(cfg, batch)
+        L = cfg.n_mamba
         cache["mamba"] = ssm_lib.MambaState(
             conv=jnp.zeros((L,) + st.conv.shape, st.conv.dtype),
             ssm=jnp.zeros((L,) + st.ssm.shape, st.ssm.dtype),
         )
         if cfg.family == "hybrid":
-            n_sites = len(_segment_bounds(cfg.n_layers,
-                                          cfg.attn_every or cfg.n_layers))
-            s = min(cfg.window, max_len) if cfg.window > 0 else max_len
-            kv = jnp.zeros((n_sites, batch, s, cfg.kv_dim), jnp.bfloat16)
-            cache["shared_k"] = ctx.shard_kv_cache(kv)
-            cache["shared_v"] = ctx.shard_kv_cache(kv)
+            kv = jnp.zeros((cfg.attn_sites, batch, s, cfg.kv_dim),
+                           jnp.bfloat16)
+            cache["attn_k"] = ctx.shard_kv_cache(kv)
+            cache["attn_v"] = ctx.shard_kv_cache(kv)
     return cache
 
 
@@ -437,17 +530,19 @@ def _decode_attn_block(x, lp, cfg, ctx, k_cache, v_cache, pos, window,
         B, 1, cfg.n_kv_heads, cfg.hd)
     v = jnp.einsum("bsd,dk->bsk", h, lp["attn"]["wv"]).reshape(
         B, 1, cfg.n_kv_heads, cfg.hd)
-    q = apply_rope(q, q_pos, cfg.rope_theta)
-    k = apply_rope(k, q_pos, cfg.rope_theta)
+    if cfg.rope:
+        q = apply_rope(q, q_pos, cfg.rope_theta)
+        k = apply_rope(k, q_pos, cfg.rope_theta)
     positions = cache_positions_ring if ring else cache_positions_full
     # the slots as writing step `pos` would leave them, less the step
     # itself, which attends as its own column
     k_pos = positions(k_cache.shape[1], pos)
     k_pos = jnp.where(k_pos < pos, k_pos, -1)
     out = attention_decode(q, k_cache, v_cache, k, v, q_pos=q_pos,
-                           k_pos=k_pos, window=window)
+                           k_pos=k_pos, window=window,
+                           scale=cfg.attention_multiplier)
     out = out.reshape(B, 1, cfg.q_dim)
-    x = x + jnp.einsum("bsq,qd->bsd", out, lp["attn"]["wo"])
+    x = _add(x, jnp.einsum("bsq,qd->bsd", out, lp["attn"]["wo"]), cfg)
     return (x, k.reshape(B, 1, cfg.kv_dim).astype(k_cache.dtype),
             v.reshape(B, 1, cfg.kv_dim).astype(v_cache.dtype))
 
@@ -466,10 +561,8 @@ def lm_decode_step(params: dict, cfg: ModelConfig, cache: dict,
                    tokens: jax.Array, ctx: ShardCtx
                    ) -> tuple[jax.Array, dict]:
     """One new token per sequence.  tokens: (B, 1).  Returns (logits, cache')."""
-    from . import ffn as ffn_lib
-
     pos = cache["pos"]
-    x = ctx.shard_act(params["embed"][tokens])
+    x = ctx.shard_act(_embed(params, cfg, tokens))
     new_cache = dict(cache)
     ring = cache_kind(cfg) == "ring"
     windows = jnp.asarray(cfg.layer_windows(), jnp.int32)
@@ -511,20 +604,7 @@ def lm_decode_step(params: dict, cfg: ModelConfig, cache: dict,
         new_cache["k"], new_cache["v"] = _store_step(
             cache["k"], cache["v"], k_step, v_step, pos, ring)
 
-    elif cfg.family == "ssm":
-        def body(h, xs):
-            lp, conv_l, ssm_l = xs
-            hn = rms_norm(h, lp["ln"], cfg.norm_eps)
-            y, st = ssm_lib.mamba_block_decode(
-                hn, lp, cfg, ssm_lib.MambaState(conv=conv_l, ssm=ssm_l))
-            return h + y, (st.conv, st.ssm)
-
-        x, (conv_new, ssm_new) = jax.lax.scan(
-            body, x, (params["layers"], cache["mamba"].conv,
-                      cache["mamba"].ssm))
-        new_cache["mamba"] = ssm_lib.MambaState(conv=conv_new, ssm=ssm_new)
-
-    elif cfg.family == "hybrid":
+    elif cfg.family in ("ssm", "hybrid"):
         x, new_cache = _hybrid_decode(params, cfg, cache, x, ctx, pos)
 
     else:
@@ -536,44 +616,43 @@ def lm_decode_step(params: dict, cfg: ModelConfig, cache: dict,
 
 
 def _hybrid_decode(params, cfg, cache, x, ctx, pos):
-    from . import ffn as ffn_lib
-
+    """One decode step through the ssm and hybrid stacks, in
+    ``cfg.hybrid_schedule()`` order: each Mamba layer reads and rewrites
+    its state in the stacked states, carried through the scans; each
+    attention site reads its cache, and one write after the stack stores
+    every site's step K/V (``_store_step``)."""
     new_cache = dict(cache)
-    bounds = _segment_bounds(cfg.n_layers, cfg.attn_every or cfg.n_layers)
-    ring = cfg.window > 0
-    conv_all, ssm_all = cache["mamba"].conv, cache["mamba"].ssm
-    conv_out, ssm_out = [], []
+    ring = cache_kind(cfg) == "ring"
     k_sites, v_sites = [], []
 
-    def seg_body(h, xs):
-        lp, conv_l, ssm_l = xs
+    def body(carry, lp, l):
+        h, states = carry
         hn = rms_norm(h, lp["ln"], cfg.norm_eps)
-        y, st = ssm_lib.mamba_block_decode(
-            hn, lp, cfg, ssm_lib.MambaState(conv=conv_l, ssm=ssm_l))
-        return h + y, (st.conv, st.ssm)
+        with jax.named_scope("ssm_step"):
+            st = _state_at(states, l)
+        y, st = ssm_lib.mamba_block_decode(hn, lp, cfg, st)
+        with jax.named_scope("ssm_step"):
+            states = _state_put(states, st, l)
+        h = _add(h, y, cfg)
+        if "mlp" in lp:
+            h = _mlp_residual(h, lp, cfg, ctx)
+        return h, states
 
-    for i, (lo, hi) in enumerate(bounds):
-        seg = _slice_layers(params["layers"], lo, hi)
-        conv_seg = jax.lax.slice_in_dim(conv_all, lo, hi, axis=0)
-        ssm_seg = jax.lax.slice_in_dim(ssm_all, lo, hi, axis=0)
-        x, (conv_n, ssm_n) = jax.lax.scan(seg_body, x, (seg, conv_seg, ssm_seg))
-        conv_out.append(conv_n)
-        ssm_out.append(ssm_n)
-        # shared attention block at the segment boundary
-        sp = params["shared_attn"]
-        x, k_t, v_t = _decode_attn_block(x, sp, cfg, ctx, cache["shared_k"][i],
-                                         cache["shared_v"][i], pos,
-                                         cfg.window, ring)
-        h2 = rms_norm(x, sp["ln2"], cfg.norm_eps)
-        x = x + ffn_lib.swiglu(h2, sp["mlp"]["w_gate"], sp["mlp"]["w_up"],
-                               sp["mlp"]["w_down"])
-        k_sites.append(k_t)
-        v_sites.append(v_t)
-
-    new_cache["mamba"] = ssm_lib.MambaState(
-        conv=jnp.concatenate(conv_out, axis=0),
-        ssm=jnp.concatenate(ssm_out, axis=0))
-    new_cache["shared_k"], new_cache["shared_v"] = _store_step(
-        cache["shared_k"], cache["shared_v"], jnp.stack(k_sites),
-        jnp.stack(v_sites), pos, ring)
+    states = cache["mamba"]
+    for kind, a, b in cfg.hybrid_schedule():
+        if kind == "mamba":
+            x, states = _scan_layers(body, (x, states), params["layers"], a, b)
+        else:
+            lp = _layer(params["attn_layers"], a)
+            x, k_t, v_t = _decode_attn_block(
+                x, lp, cfg, ctx, cache["attn_k"][b], cache["attn_v"][b], pos,
+                cfg.window, ring)
+            x = _mlp_residual(x, lp, cfg, ctx)
+            k_sites.append(k_t)
+            v_sites.append(v_t)
+    new_cache["mamba"] = states
+    if k_sites:
+        new_cache["attn_k"], new_cache["attn_v"] = _store_step(
+            cache["attn_k"], cache["attn_v"], jnp.stack(k_sites),
+            jnp.stack(v_sites), pos, ring)
     return x, new_cache

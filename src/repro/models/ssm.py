@@ -10,9 +10,10 @@ of length Q; within a chunk the dual quadratic (attention-like) form is
 used, and a single inter-chunk recurrence over ``S/Q`` steps carries the
 state — O(S Q) work, sub-quadratic in S, and TPU-friendly (the intra-chunk
 form is batched matmuls on the MXU).  ``repro.kernels.ssd_scan`` holds the
-Pallas kernel for the intra-chunk core; this module is the pure-jnp
-reference implementation the kernel is validated against (the model layer
-can route through either).
+Pallas kernel of the whole chunked scan; ``ssd_chunked`` here is the
+pure-jnp implementation the kernel is validated against.  A prefill on a
+TPU runs the kernel; training runs ``ssd_chunked``, since the kernel has
+no backward pass.
 
 Decode is O(1) in sequence length: one multiply-accumulate against the
 (H, P, N) state — this is why the ssm/hybrid archs run the ``long_500k``
@@ -26,11 +27,13 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
+
 from .config import ModelConfig
 
 
 class MambaState(NamedTuple):
-    conv: jax.Array         # (B, conv_width-1, conv_dim) rolling conv input
+    conv: jax.Array         # (B, conv_width-1, conv_dim) rolling conv input (f32)
     ssm: jax.Array          # (B, H, P, N) recurrent state (f32)
 
 
@@ -85,20 +88,19 @@ def ssd_chunked(
     cum = jnp.cumsum(dA, axis=2)                          # inclusive cumsum
     total = cum[:, :, -1, :]                              # (B,nc,H)
 
-    # intra-chunk (dual quadratic form): L[i,j] = exp(cum_i - cum_j) * dt_j, j<=i
-    # (named_scope: the Pallas ssd_scan kernel fuses this region — the
-    # roofline engine separates its bytes; see core/fidelity.py)
-    with jax.named_scope("flashable_ssd"):
-        li = cum[:, :, :, None, :] - cum[:, :, None, :, :]    # (B,nc,Q,Q,H)
-        mask = jnp.tril(jnp.ones((chunk, chunk), bool))
-        L = jnp.where(mask[None, None, :, :, None], jnp.exp(li), 0.0)
-        L = L * dtc[:, :, None, :, :]                         # x dt_j
-        # scores_ij = C_i . B_j (group-shared across rep heads)
-        CB = jnp.einsum("bnigx,bnjgx->bnijg", Cc.astype(jnp.float32),
-                        Bc.astype(jnp.float32))               # (B,nc,Q,Q,G)
-        CB = jnp.repeat(CB, rep, axis=-1)                     # (B,nc,Q,Q,H)
-        W = CB * L                                            # (B,nc,Q,Q,H)
-        y_intra = jnp.einsum("bnijh,bnjhp->bnihp", W, xc.astype(jnp.float32))
+    # intra-chunk (dual quadratic form): L[i,j] = exp(cum_i - cum_j) * dt_j,
+    # j <= i.  The exponent is formed only there: above the diagonal it
+    # grows to hundreds, and exp's gradient would be inf times the mask's 0
+    li = cum[:, :, :, None, :] - cum[:, :, None, :, :]        # (B,nc,Q,Q,H)
+    mask = jnp.tril(jnp.ones((chunk, chunk), bool))[None, None, :, :, None]
+    L = jnp.exp(jnp.where(mask, li, -jnp.inf))
+    L = L * dtc[:, :, None, :, :]                             # x dt_j
+    # scores_ij = C_i . B_j (group-shared across rep heads)
+    CB = jnp.einsum("bnigx,bnjgx->bnijg", Cc.astype(jnp.float32),
+                    Bc.astype(jnp.float32))                   # (B,nc,Q,Q,G)
+    CB = jnp.repeat(CB, rep, axis=-1)                         # (B,nc,Q,Q,H)
+    W = CB * L                                                # (B,nc,Q,Q,H)
+    y_intra = jnp.einsum("bnijh,bnjhp->bnihp", W, xc.astype(jnp.float32))
 
     # chunk states: S_c = sum_j exp(total - cum_j) dt_j B_j x_j^T
     decay_to_end = jnp.exp(total[:, :, None, :] - cum)    # (B,nc,Q,H)
@@ -147,10 +149,12 @@ def ssd_decode_step(
     dec = jnp.exp(dt * A[None, :])                        # (B,H)
     Bh = jnp.repeat(Bm, rep, axis=1).astype(jnp.float32)  # (B,H,N)
     Ch = jnp.repeat(Cm, rep, axis=1).astype(jnp.float32)
-    upd = dt[:, :, None, None] * jnp.einsum(
-        "bhn,bhp->bhpn", Bh, x.astype(jnp.float32))
+    # elementwise in f32: as a contraction the state would be rounded to
+    # the matrix unit's bf16 on a TPU at the default precision
+    upd = (dt[:, :, None, None] * x.astype(jnp.float32)[..., None]
+           * Bh[:, :, None, :])
     new_state = state * dec[:, :, None, None] + upd
-    y = jnp.einsum("bhpn,bhn->bhp", new_state, Ch)
+    y = jnp.sum(new_state * Ch[:, :, None, :], axis=-1)
     return y.astype(x.dtype), new_state
 
 
@@ -159,10 +163,22 @@ def ssd_decode_step(
 # ---------------------------------------------------------------------------
 
 
+def _conv(taps: list[jax.Array], w: jax.Array, b: jax.Array) -> jax.Array:
+    """silu of the causal depthwise conv, taps oldest first, elementwise
+    in f32: on a TPU an f32 contraction would round its operands to the
+    matrix unit's bf16 at the default precision."""
+    acc = b.astype(jnp.float32)
+    for i, tap in enumerate(taps):
+        acc = acc + tap.astype(jnp.float32) * w[i].astype(jnp.float32)
+    return jax.nn.silu(acc)
+
+
 def mamba_block_train(x: jax.Array, p: dict, cfg: ModelConfig,
-                      *, impl: str = "ref", shard_heads=None,
-                      return_state: bool = False):
-    """(B, S, D) -> (B, S, D)  [or (y, MambaState) with return_state]."""
+                      *, shard_heads=None, return_state: bool = False):
+    """(B, S, D) -> (B, S, D)  [or (y, MambaState) with return_state].
+
+    With ``return_state`` (a prefill) the SSD runs the Pallas kernel on a
+    TPU; otherwise, and for training, ``ssd_chunked``."""
     s = cfg.ssm
     Bsz, S, D = x.shape
     H, Pd, N, G = cfg.ssm_heads, s.head_dim, s.d_state, s.n_groups
@@ -171,14 +187,9 @@ def mamba_block_train(x: jax.Array, p: dict, cfg: ModelConfig,
 
     # causal depthwise conv over (x, B, C)
     xbc_raw = jnp.concatenate([xin, Bm, Cm], axis=-1)     # (B,S,conv_dim)
-    xbc = xbc_raw
-    pad = jnp.pad(xbc, ((0, 0), (s.conv_width - 1, 0), (0, 0)))
-    windows = jnp.stack(
-        [pad[:, i:i + S] for i in range(s.conv_width)], axis=2)  # (B,S,W,C)
-    xbc = jax.nn.silu(
-        (jnp.einsum("bswc,wc->bsc", windows.astype(jnp.float32),
-                    p["conv_w"].astype(jnp.float32))
-         + p["conv_b"].astype(jnp.float32))).astype(x.dtype)
+    pad = jnp.pad(xbc_raw, ((0, 0), (s.conv_width - 1, 0), (0, 0)))
+    xbc = _conv([pad[:, i:i + S] for i in range(s.conv_width)],
+                p["conv_w"], p["conv_b"]).astype(x.dtype)
     xin, Bm, Cm = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + G * N], axis=-1)
 
     xh = xin.reshape(Bsz, S, H, Pd)
@@ -189,17 +200,26 @@ def mamba_block_train(x: jax.Array, p: dict, cfg: ModelConfig,
     dtf = _dt_activation(dt, p["dt_bias"])                   # (B,S,H) f32
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
-    if impl == "pallas" and not return_state:
-        from repro.kernels import ops as kops
-        y, final_state = kops.ssd_scan(xh, dtf, A, Bg, Cg, chunk=s.chunk), None
-    else:
-        y, final_state = ssd_chunked(xh, dtf, A, Bg, Cg, s.chunk)
+    # the whole SSD, chunk states included, whichever implementation runs;
+    # a sequence that ends inside a chunk is padded with dt = 0, which
+    # leaves the state as the last real position left it
+    with jax.named_scope("flashable_ssd"):
+        pad = (-S) % s.chunk
+        args = [jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
+                for a in (xh, dtf, Bg, Cg)] if pad else [xh, dtf, Bg, Cg]
+        if return_state and kops.on_tpu():
+            y, final_state = kops.ssd_scan(args[0], args[1], A, *args[2:],
+                                           chunk=s.chunk)
+        else:
+            y, final_state = ssd_chunked(args[0], args[1], A, *args[2:],
+                                         s.chunk)
+        y = y[:, :S]
     y = y + xh * p["D"].astype(x.dtype)[None, None, :, None]
     y = y.reshape(Bsz, S, cfg.d_inner)
     y = _gated_norm(y, z, p["norm_w"], cfg.norm_eps)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"])
     if return_state:
-        conv_state = xbc_raw[:, S - (s.conv_width - 1):, :].astype(jnp.bfloat16)
+        conv_state = xbc_raw[:, S - (s.conv_width - 1):, :].astype(jnp.float32)
         return out, MambaState(conv=conv_state, ssm=final_state)
     return out
 
@@ -213,21 +233,22 @@ def mamba_block_decode(x: jax.Array, p: dict, cfg: ModelConfig,
     zxbcdt = jnp.einsum("bsd,de->bse", x, p["in_proj"])[:, 0]
     z, xin, Bm, Cm, dt = _split_proj(cfg, zxbcdt)
 
-    xbc_new = jnp.concatenate([xin, Bm, Cm], axis=-1)     # (B, conv_dim)
-    conv_in = jnp.concatenate([state.conv, xbc_new[:, None, :]], axis=1)
-    xbc = jax.nn.silu(
-        (jnp.einsum("bwc,wc->bc", conv_in.astype(jnp.float32),
-                    p["conv_w"].astype(jnp.float32))
-         + p["conv_b"].astype(jnp.float32))).astype(x.dtype)
-    new_conv = conv_in[:, 1:, :]
+    # the step's conv and state update: what reads and writes the state
+    with jax.named_scope("ssm_step"):
+        xbc_new = jnp.concatenate([xin, Bm, Cm], axis=-1)     # (B, conv_dim)
+        conv_in = jnp.concatenate([state.conv, xbc_new[:, None, :]], axis=1)
+        xbc = _conv([conv_in[:, i] for i in range(s.conv_width)],
+                    p["conv_w"], p["conv_b"]).astype(x.dtype)
+        new_conv = conv_in[:, 1:, :]
 
-    xin, Bm, Cm = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + G * N], axis=-1)
-    xh = xin.reshape(Bsz, H, Pd)
-    Bg = Bm.reshape(Bsz, G, N)
-    Cg = Cm.reshape(Bsz, G, N)
-    dtf = _dt_activation(dt, p["dt_bias"])                   # (B,H)
-    A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, new_ssm = ssd_decode_step(xh, dtf, A, Bg, Cg, state.ssm)
+        xin, Bm, Cm = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + G * N],
+                                axis=-1)
+        xh = xin.reshape(Bsz, H, Pd)
+        Bg = Bm.reshape(Bsz, G, N)
+        Cg = Cm.reshape(Bsz, G, N)
+        dtf = _dt_activation(dt, p["dt_bias"])                   # (B,H)
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
+        y, new_ssm = ssd_decode_step(xh, dtf, A, Bg, Cg, state.ssm)
     y = y + xh * p["D"].astype(x.dtype)[None, :, None]
     y = y.reshape(Bsz, cfg.d_inner)
     y = _gated_norm(y, z, p["norm_w"], cfg.norm_eps)
@@ -238,7 +259,9 @@ def mamba_block_decode(x: jax.Array, p: dict, cfg: ModelConfig,
 def init_mamba_state(cfg: ModelConfig, batch: int) -> MambaState:
     s = cfg.ssm
     return MambaState(
-        conv=jnp.zeros((batch, s.conv_width - 1, cfg.conv_dim), jnp.bfloat16),
+        # f32, as the SSM state: the conv's inputs exactly, whatever the
+        # weights' type, at a fortieth of the SSM state's bytes
+        conv=jnp.zeros((batch, s.conv_width - 1, cfg.conv_dim), jnp.float32),
         ssm=jnp.zeros((batch, cfg.ssm_heads, s.head_dim, s.d_state), jnp.float32),
     )
 

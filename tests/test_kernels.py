@@ -94,12 +94,38 @@ def test_ssd_scan_sweep(B, H, S, P, N, G, chunk, dtype):
     A = -jnp.exp(_rand(jax.random.fold_in(k, 2), (H,)) * 0.3)
     Bm = _rand(jax.random.fold_in(k, 3), (B, G, S, N), dtype)
     Cm = _rand(jax.random.fold_in(k, 4), (B, G, S, N), dtype)
-    y = ssd_scan_bhsd(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
+    y, _ = ssd_scan_bhsd(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     expect = ref.ssd_scan_ref(x, dt, A, Bm, Cm, chunk=chunk)
     np.testing.assert_allclose(np.asarray(y, np.float32),
                                np.asarray(expect, np.float32),
                                atol=5e-2 if dtype == jnp.bfloat16 else 1e-4,
                                rtol=5e-2 if dtype == jnp.bfloat16 else 1e-4)
+
+
+@pytest.mark.parametrize("B,H,S,P,N,G,chunk", [
+    (2, 4, 64, 16, 16, 1, 16),
+    (1, 4, 128, 16, 32, 2, 32),
+])
+def test_ssd_scan_final_state_matches_chunked(B, H, S, P, N, G, chunk):
+    """The kernel's second output, the state after the last chunk, is
+    ``ssd_chunked``'s final state: what a prefill hands to decode."""
+    from repro.models.ssm import ssd_chunked
+    k = jax.random.PRNGKey(S * H + N)
+    x = _rand(k, (B, H, S, P), jnp.bfloat16)
+    dt = jax.nn.softplus(_rand(jax.random.fold_in(k, 1), (B, H, S)))
+    A = -jnp.exp(_rand(jax.random.fold_in(k, 2), (H,)) * 0.3)
+    Bm = _rand(jax.random.fold_in(k, 3), (B, G, S, N), jnp.bfloat16)
+    Cm = _rand(jax.random.fold_in(k, 4), (B, G, S, N), jnp.bfloat16)
+    y, state = ssd_scan_bhsd(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
+    y_ref, state_ref = ssd_chunked(
+        jnp.moveaxis(x, 1, 2), jnp.moveaxis(dt, 1, 2), A,
+        jnp.moveaxis(Bm, 1, 2), jnp.moveaxis(Cm, 1, 2), chunk)
+    assert state.shape == (B, H, P, N) and state.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(state), np.asarray(state_ref),
+                               atol=1e-3, rtol=1e-3)
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(jnp.moveaxis(y_ref, 2, 1),
+                                          np.float32), atol=5e-2, rtol=5e-2)
 
 
 def test_ssd_state_continuity_across_chunks():
@@ -111,9 +137,9 @@ def test_ssd_state_continuity_across_chunks():
     A = -jnp.ones((H,)) * 0.01           # slow decay: long memory
     Bm = _rand(jax.random.fold_in(k, 3), (B, 1, S, N))
     Cm = _rand(jax.random.fold_in(k, 4), (B, 1, S, N))
-    y1 = ssd_scan_bhsd(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
+    y1, _ = ssd_scan_bhsd(x, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     x2 = x.at[:, :, 0].add(1.0)          # perturb first chunk only
-    y2 = ssd_scan_bhsd(x2, dt, A, Bm, Cm, chunk=chunk, interpret=True)
+    y2, _ = ssd_scan_bhsd(x2, dt, A, Bm, Cm, chunk=chunk, interpret=True)
     # last chunk outputs must differ -> state flowed across chunks
     assert not np.allclose(np.asarray(y1[:, :, -chunk:]),
                            np.asarray(y2[:, :, -chunk:]), atol=1e-6)

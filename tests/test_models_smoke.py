@@ -109,6 +109,7 @@ def test_full_configs_match_published_sizes():
         "qwen3-moe-30b-a3b": (29e9, 32e9),
         "mamba2-1.3b": (1.2e9, 1.5e9),
         "zamba2-1.2b": (1.0e9, 1.3e9),
+        "granite-4.0-h-micro": (3.1e9, 3.3e9),
         "llava-next-mistral-7b": (7.0e9, 7.6e9),
         "smollm-360m": (0.3e9, 0.5e9),
         "gemma3-1b": (1.0e9, 1.4e9),

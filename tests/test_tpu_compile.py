@@ -23,7 +23,7 @@ from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.quantize import dequantize_int8, quantize_int8
 from repro.kernels.ssd_scan import ssd_scan_bhsd
 from repro.launch.serve import Server
-from repro.models import ModelConfig
+from repro.models import ModelConfig, SSMConfig
 
 
 @pytest.fixture(scope="module")
@@ -90,6 +90,48 @@ def test_ssd_scan_mamba2_1_3b_widths(one_chip):
         s((B, H, S, P), jnp.bfloat16), s((B, H, S), jnp.float32),
         s((H,), jnp.float32), s((B, G, S, N), jnp.bfloat16),
         s((B, G, S, N), jnp.bfloat16))
+
+
+def test_ssd_scan_with_state_granite_prefill(one_chip):
+    """The kernel with its final state, at granite-4.0-h-micro's prefill:
+    one piece of the batch (4 x 2048 tokens), 64 heads of 64, state 128."""
+    B, H, S, P, N, G = 4, 64, 2048, 64, 128, 1
+    s = functools.partial(_spec, one_chip)
+    fn = functools.partial(ssd_scan_bhsd, chunk=256)
+    args = (s((B, H, S, P), jnp.bfloat16), s((B, H, S), jnp.float32),
+            s((H,), jnp.float32), s((B, G, S, N), jnp.bfloat16),
+            s((B, G, S, N), jnp.bfloat16))
+    _compiles_to_mosaic(fn, *args)
+    y, state = jax.eval_shape(fn, *args)
+    assert y.shape == (B, H, S, P)
+    assert state.shape == (B, H, P, N) and state.dtype == jnp.float32
+
+
+def test_hybrid_decode_step_updates_states_in_place(one_chip):
+    """Granite-4.0-H's decode step at its widths and the cell's batch, on
+    three layers: the donated Mamba states and K/V are aliased, and no
+    temporary holds as much as one layer's SSM state."""
+    cfg = ModelConfig(name="granite-3l", family="hybrid", n_layers=3,
+                      d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64,
+                      d_ff=8192, vocab=512,
+                      ssm=SSMConfig(d_state=128, head_dim=64, chunk=256),
+                      layer_types=("mamba", "attention", "mamba"), rope=False,
+                      embedding_multiplier=12.0, residual_multiplier=0.22,
+                      attention_multiplier=1 / 64, logits_scaling=8.0,
+                      tie_embeddings=True)
+    B, max_len = 32, 2304
+    server = Server(cfg, max_len=max_len)
+    place = functools.partial(jax.tree.map, lambda a: _spec(
+        one_chip, a.shape, a.dtype))
+    params = place(jax.eval_shape(server.api.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: server.api.init_cache(B, max_len, server.ctx)))
+    m = server._decode.lower(params, cache, _spec(
+        one_chip, (B, 1), jnp.int32)).compile().memory_analysis()
+    state = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert m.alias_size_in_bytes >= state - 4
+    one_layer = B * cfg.ssm_heads * 64 * 128 * 4
+    assert m.temp_size_in_bytes < one_layer, m.temp_size_in_bytes
 
 
 def test_block_digest_4mib_panels(one_chip):
