@@ -8,6 +8,7 @@ Kernels:
 * flash_attention — blocked online-softmax GQA attention (train/prefill)
 * decode_attention — flash-decode vs long (possibly ring) KV caches
 * ssd_scan — full chunked Mamba2/SSD with in-VMEM recurrent state
+* ssm_update — a decode's one-token SSD step, in place in the stacked states
 * quantize — blockwise int8 for the compressed gradient collective
 * digest — blockwise lattice digest for accelerator-placed integrity
 """

@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels — the one backend seam.
 
 Model code calls these (attention through ``ShardCtx.impl == "pallas"``,
-a prefill's SSD on any TPU) and the integrity layer calls
-:func:`block_digest`.  :func:`on_tpu` alone decides
+a prefill's SSD and a decode's state update where :func:`use_kernel`)
+and the integrity layer calls :func:`block_digest`.  :func:`on_tpu` alone decides
 how a kernel runs: compiled by Mosaic on a TPU backend, in interpret mode
 (kernel bodies run as Python over numpy, for correctness) on any other.
 Layout conversions between the model's (B, S, H, hd) convention and the
@@ -19,10 +19,18 @@ from .digest import block_digest as _block_digest
 from .flash_attention import flash_attention_bhsd
 from .quantize import dequantize_int8, quantize_int8
 from .ssd_scan import ssd_scan_bhsd
+from .ssm_update import ssm_update_stacked
 
 
 def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def use_kernel(mesh) -> bool:
+    """Whether model code runs a Pallas kernel where it has one: on a TPU,
+    and not under a mesh, over which XLA cannot partition a kernel's
+    custom call (it would gather the operands to every device)."""
+    return on_tpu() and mesh is None
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -63,6 +71,17 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
                              A.astype(jnp.float32), Bt, Ct, chunk=chunk,
                              interpret=not on_tpu())
     return jnp.moveaxis(y, 1, 2), final
+
+
+def ssm_update(states: jax.Array, l: jax.Array, x: jax.Array, dt: jax.Array,
+               A: jax.Array, Bm: jax.Array, Cm: jax.Array
+               ) -> tuple[jax.Array, jax.Array]:
+    """One-token SSD step of layer ``l`` in the stacked f32 states
+    (L, B, H, P, N), in place: x (B, H, P); dt (B, H); Bm/Cm (B, G, N) ->
+    (y (B, H, P), states)."""
+    return ssm_update_stacked(states, l, x, dt.astype(jnp.float32),
+                              A.astype(jnp.float32), Bm, Cm,
+                              interpret=not on_tpu())
 
 
 def quantize(x: jax.Array, *, block: int = 256):

@@ -144,7 +144,8 @@ def _mamba_layer_apply(x, lp, cfg, ctx, return_state: bool = False):
     has one; with ``return_state`` also the layer's MambaState."""
     h = rms_norm(x, lp["ln"], cfg.norm_eps)
     out = ssm_lib.mamba_block_train(h, lp, cfg, shard_heads=ctx.shard_heads,
-                                    return_state=return_state)
+                                    return_state=return_state,
+                                    mesh=ctx.mesh)
     y, st = out if return_state else (out, None)
     x = ctx.shard_act(_add(x, y, cfg))
     if "mlp" in lp:
@@ -363,13 +364,6 @@ def _pack_kv(kv: jax.Array, ring: bool, s_cache: int) -> jax.Array:
     if ring:
         return _ring_pack(kv, s_cache).astype(jnp.bfloat16)
     return jnp.pad(kv, ((0, 0), (0, s_cache - S), (0, 0))).astype(jnp.bfloat16)
-
-
-def _state_at(st: "ssm_lib.MambaState", l) -> "ssm_lib.MambaState":
-    """Layer ``l``'s state out of the stacked states."""
-    return ssm_lib.MambaState(
-        conv=jax.lax.dynamic_index_in_dim(st.conv, l, keepdims=False),
-        ssm=jax.lax.dynamic_index_in_dim(st.ssm, l, keepdims=False))
 
 
 def _state_put(st: "ssm_lib.MambaState", new: "ssm_lib.MambaState", l
@@ -628,11 +622,8 @@ def _hybrid_decode(params, cfg, cache, x, ctx, pos):
     def body(carry, lp, l):
         h, states = carry
         hn = rms_norm(h, lp["ln"], cfg.norm_eps)
-        with jax.named_scope("ssm_step"):
-            st = _state_at(states, l)
-        y, st = ssm_lib.mamba_block_decode(hn, lp, cfg, st)
-        with jax.named_scope("ssm_step"):
-            states = _state_put(states, st, l)
+        y, states = ssm_lib.mamba_block_decode(
+            hn, lp, cfg, states, l, mesh=ctx.mesh)
         h = _add(h, y, cfg)
         if "mlp" in lp:
             h = _mlp_residual(h, lp, cfg, ctx)

@@ -17,7 +17,9 @@ no backward pass.
 
 Decode is O(1) in sequence length: one multiply-accumulate against the
 (H, P, N) state — this is why the ssm/hybrid archs run the ``long_500k``
-cell that pure-attention archs skip.
+cell that pure-attention archs skip.  On a TPU ``repro.kernels.ssm_update``
+does it in place in the stacked states, one pass over each layer's state;
+``ssd_decode_step`` here is its oracle.
 """
 
 from __future__ import annotations
@@ -174,11 +176,13 @@ def _conv(taps: list[jax.Array], w: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def mamba_block_train(x: jax.Array, p: dict, cfg: ModelConfig,
-                      *, shard_heads=None, return_state: bool = False):
+                      *, shard_heads=None, return_state: bool = False,
+                      mesh=None):
     """(B, S, D) -> (B, S, D)  [or (y, MambaState) with return_state].
 
-    With ``return_state`` (a prefill) the SSD runs the Pallas kernel on a
-    TPU; otherwise, and for training, ``ssd_chunked``."""
+    With ``return_state`` (a prefill) the SSD runs the Pallas kernel where
+    ``kops.use_kernel(mesh)``; otherwise, and for training,
+    ``ssd_chunked``."""
     s = cfg.ssm
     Bsz, S, D = x.shape
     H, Pd, N, G = cfg.ssm_heads, s.head_dim, s.d_state, s.n_groups
@@ -207,7 +211,7 @@ def mamba_block_train(x: jax.Array, p: dict, cfg: ModelConfig,
         pad = (-S) % s.chunk
         args = [jnp.pad(a, [(0, 0), (0, pad)] + [(0, 0)] * (a.ndim - 2))
                 for a in (xh, dtf, Bg, Cg)] if pad else [xh, dtf, Bg, Cg]
-        if return_state and kops.on_tpu():
+        if return_state and kops.use_kernel(mesh):
             y, final_state = kops.ssd_scan(args[0], args[1], A, *args[2:],
                                            chunk=s.chunk)
         else:
@@ -225,8 +229,14 @@ def mamba_block_train(x: jax.Array, p: dict, cfg: ModelConfig,
 
 
 def mamba_block_decode(x: jax.Array, p: dict, cfg: ModelConfig,
-                       state: MambaState) -> tuple[jax.Array, MambaState]:
-    """(B, 1, D) one-token step with rolling conv + SSM state."""
+                       states: MambaState, l: jax.Array, *,
+                       mesh=None) -> tuple[jax.Array, MambaState]:
+    """(B, 1, D) one-token step of Mamba layer ``l``, which reads and
+    rewrites its rolling conv and SSM state in ``states``, every layer's
+    stacked (L, ...).  Where ``kops.use_kernel(mesh)`` the SSM state is
+    updated in place in the stack by the ``ssm_update`` kernel, read once
+    and written once; elsewhere by ``ssd_decode_step`` on the layer's
+    slice, written back."""
     s = cfg.ssm
     Bsz = x.shape[0]
     H, Pd, N, G = cfg.ssm_heads, s.head_dim, s.d_state, s.n_groups
@@ -235,11 +245,13 @@ def mamba_block_decode(x: jax.Array, p: dict, cfg: ModelConfig,
 
     # the step's conv and state update: what reads and writes the state
     with jax.named_scope("ssm_step"):
+        conv = jax.lax.dynamic_index_in_dim(states.conv, l, keepdims=False)
         xbc_new = jnp.concatenate([xin, Bm, Cm], axis=-1)     # (B, conv_dim)
-        conv_in = jnp.concatenate([state.conv, xbc_new[:, None, :]], axis=1)
+        conv_in = jnp.concatenate([conv, xbc_new[:, None, :]], axis=1)
         xbc = _conv([conv_in[:, i] for i in range(s.conv_width)],
                     p["conv_w"], p["conv_b"]).astype(x.dtype)
-        new_conv = conv_in[:, 1:, :]
+        new_conv = jax.lax.dynamic_update_index_in_dim(
+            states.conv, conv_in[:, 1:, :].astype(states.conv.dtype), l, 0)
 
         xin, Bm, Cm = jnp.split(xbc, [cfg.d_inner, cfg.d_inner + G * N],
                                 axis=-1)
@@ -248,7 +260,13 @@ def mamba_block_decode(x: jax.Array, p: dict, cfg: ModelConfig,
         Cg = Cm.reshape(Bsz, G, N)
         dtf = _dt_activation(dt, p["dt_bias"])                   # (B,H)
         A = -jnp.exp(p["A_log"].astype(jnp.float32))
-        y, new_ssm = ssd_decode_step(xh, dtf, A, Bg, Cg, state.ssm)
+        if kops.use_kernel(mesh):
+            y, new_ssm = kops.ssm_update(states.ssm, l, xh, dtf, A, Bg, Cg)
+        else:
+            y, st = ssd_decode_step(xh, dtf, A, Bg, Cg,
+                                    jax.lax.dynamic_index_in_dim(
+                                        states.ssm, l, keepdims=False))
+            new_ssm = jax.lax.dynamic_update_index_in_dim(states.ssm, st, l, 0)
     y = y + xh * p["D"].astype(x.dtype)[None, :, None]
     y = y.reshape(Bsz, cfg.d_inner)
     y = _gated_norm(y, z, p["norm_w"], cfg.norm_eps)

@@ -127,7 +127,7 @@ def test_prefill_through_kernel_matches_chunked(monkeypatch):
     prefill = lambda p, b: api.prefill(p, b, CTX, max_len=20)  # noqa: E731
     want_logits, want = jax.jit(prefill)(params, {"tokens": tok})
     monkeypatch.setattr(ssm_lib, "kops", types.SimpleNamespace(
-        on_tpu=lambda: True, ssd_scan=kops.ssd_scan))
+        use_kernel=lambda mesh: mesh is None, ssd_scan=kops.ssd_scan))
     got_logits, got = jax.jit(prefill)(params, {"tokens": tok})
     np.testing.assert_allclose(np.asarray(got["mamba"].ssm),
                                np.asarray(want["mamba"].ssm), atol=2e-4,

@@ -11,6 +11,7 @@ from repro.kernels.decode_attention import decode_attention_bhd
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.quantize import dequantize_int8, quantize_int8
 from repro.kernels.ssd_scan import ssd_scan_bhsd
+from repro.kernels.ssm_update import block_shape, ssm_update_stacked
 
 
 def _rand(key, shape, dtype=jnp.float32):
@@ -143,6 +144,61 @@ def test_ssd_state_continuity_across_chunks():
     # last chunk outputs must differ -> state flowed across chunks
     assert not np.allclose(np.asarray(y1[:, :, -chunk:]),
                            np.asarray(y2[:, :, -chunk:]), atol=1e-6)
+
+
+def _ssm_update_case(B, H, P, N, G, L=3):
+    k = jax.random.PRNGKey(B * H + N + G)
+    states = _rand(k, (L, B, H, P, N))
+    x = _rand(jax.random.fold_in(k, 1), (B, H, P), jnp.bfloat16)
+    dt = jax.nn.softplus(_rand(jax.random.fold_in(k, 2), (B, H)))
+    A = -jnp.exp(_rand(jax.random.fold_in(k, 3), (H,)) * 0.3)
+    Bm = _rand(jax.random.fold_in(k, 4), (B, G, N), jnp.bfloat16)
+    Cm = _rand(jax.random.fold_in(k, 5), (B, G, N), jnp.bfloat16)
+    return states, x, dt, A, Bm, Cm
+
+
+def _check_ssm_update(states, x, dt, A, Bm, Cm):
+    """The middle layer of the stack updated as ``ssd_decode_step``
+    updates it, y as it forms y, and every other layer left bit for bit."""
+    from repro.models.ssm import ssd_decode_step
+    l = 1
+    y, new = ssm_update_stacked(states, jnp.int32(l), x, dt, A, Bm, Cm,
+                                interpret=True)
+    y_ref, st_ref = ssd_decode_step(x, dt, A, Bm, Cm, states[l])
+    assert y.dtype == y_ref.dtype and new.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(new[l]), np.asarray(st_ref),
+                               **TOL[jnp.float32])
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(y_ref, np.float32),
+                               **TOL[jnp.bfloat16])
+    # y before its cast to x's dtype
+    y32, _ = ssm_update_stacked(states, jnp.int32(l), x.astype(jnp.float32),
+                                dt, A, Bm, Cm, interpret=True)
+    y32_ref, _ = ssd_decode_step(x.astype(jnp.float32), dt, A, Bm, Cm,
+                                 states[l])
+    np.testing.assert_allclose(np.asarray(y32), np.asarray(y32_ref),
+                               **TOL[jnp.float32])
+    for other in range(states.shape[0]):
+        if other != l:
+            np.testing.assert_array_equal(np.asarray(new[other]),
+                                          np.asarray(states[other]))
+
+
+@pytest.mark.parametrize("B", [1, 3])
+@pytest.mark.parametrize("H", [8, 64])
+@pytest.mark.parametrize("N", [64, 128])
+@pytest.mark.parametrize("G", [1, 2])
+def test_ssm_update_sweep(B, H, N, G):
+    _check_ssm_update(*_ssm_update_case(B, H, 64, N, G))
+
+
+@pytest.mark.parametrize("G", [1, 2, 8])
+def test_ssm_update_head_blocks(G):
+    """128 heads of 64 x 128 are 4 MiB a row, so blocks of 64 heads: each
+    head block finds its heads' groups, whose edges need not be the
+    block's."""
+    assert block_shape(2, 128, 64, 128, G) == (1, 64)
+    _check_ssm_update(*_ssm_update_case(2, 128, 64, 128, G))
 
 
 @pytest.mark.parametrize("n", [256, 1000, 8192, 250_000])

@@ -17,11 +17,13 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops as kops
 from repro.kernels.decode_attention import decode_attention_bhd
 from repro.kernels.digest import block_digest
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.quantize import dequantize_int8, quantize_int8
 from repro.kernels.ssd_scan import ssd_scan_bhsd
+from repro.kernels.ssm_update import ssm_update_stacked
 from repro.launch.serve import Server
 from repro.models import ModelConfig, SSMConfig
 
@@ -107,10 +109,34 @@ def test_ssd_scan_with_state_granite_prefill(one_chip):
     assert state.shape == (B, H, P, N) and state.dtype == jnp.float32
 
 
-def test_hybrid_decode_step_updates_states_in_place(one_chip):
+def test_ssm_update_granite_decode(one_chip):
+    """The decode's state update at granite-4.0-h-micro's widths and the
+    cell's batch: every Mamba-2 layer's f32 state stacked (36 layers, 32
+    rows, 64 heads of 64, state 128), updated in place at one layer."""
+    L, B, H, P, N, G = 36, 32, 64, 64, 128, 1
+    s = functools.partial(_spec, one_chip)
+    args = (s((L, B, H, P, N), jnp.float32), s((), jnp.int32),
+            s((B, H, P), jnp.bfloat16), s((B, H), jnp.float32),
+            s((H,), jnp.float32), s((B, G, N), jnp.bfloat16),
+            s((B, G, N), jnp.bfloat16))
+    compiled = jax.jit(ssm_update_stacked, donate_argnums=0).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    states = L * B * H * P * N * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= states
+    y, new = jax.eval_shape(ssm_update_stacked, *args)
+    assert y.shape == (B, H, P) and y.dtype == jnp.bfloat16
+    assert new.shape == (L, B, H, P, N) and new.dtype == jnp.float32
+
+
+def test_hybrid_decode_step_updates_states_in_place(one_chip, monkeypatch):
     """Granite-4.0-H's decode step at its widths and the cell's batch, on
-    three layers: the donated Mamba states and K/V are aliased, and no
-    temporary holds as much as one layer's SSM state."""
+    three layers, as a TPU runs it: the SSM states are updated by the
+    ``ssm_update`` kernel, which takes and returns the whole stack, the
+    donated Mamba states and K/V are aliased, and no temporary holds as
+    much as one layer's SSM state."""
+    # the model picks the kernel by the default backend, here the CPU's
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
     cfg = ModelConfig(name="granite-3l", family="hybrid", n_layers=3,
                       d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64,
                       d_ff=8192, vocab=512,
@@ -126,12 +152,65 @@ def test_hybrid_decode_step_updates_states_in_place(one_chip):
     params = place(jax.eval_shape(server.api.init, jax.random.PRNGKey(0)))
     cache = place(jax.eval_shape(
         lambda: server.api.init_cache(B, max_len, server.ctx)))
-    m = server._decode.lower(params, cache, _spec(
-        one_chip, (B, 1), jnp.int32)).compile().memory_analysis()
+    compiled = server._decode.lower(params, cache, _spec(
+        one_chip, (B, 1), jnp.int32)).compile()
+    # a layer sliced out for the kernel can sit in VMEM, unseen by the
+    # bounds below: each call's first result is the whole stack
+    stack = "f32[%s]" % ",".join(map(str, cache["mamba"].ssm.shape))
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(f"= ({stack}" in line for line in calls), calls
+    m = compiled.memory_analysis()
     state = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert m.alias_size_in_bytes >= state - 4
     one_layer = B * cfg.ssm_heads * 64 * 128 * 4
     assert m.temp_size_in_bytes < one_layer, m.temp_size_in_bytes
+
+
+def _hybrid_on_and_off_a_mesh(topo):
+    """A small hybrid's Server on the 2x2 mesh and on one chip, each with
+    its params and a cache of batch 4 placed as that Server holds them."""
+    cfg = ModelConfig(name="hybrid-3l", family="hybrid", n_layers=3,
+                      d_model=256, n_heads=4, n_kv_heads=2, head_dim=64,
+                      d_ff=512, vocab=512,
+                      ssm=SSMConfig(d_state=128, head_dim=64, chunk=256),
+                      layer_types=("mamba", "attention", "mamba"), rope=False)
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=topo.devices)
+    B, max_len = 4, 256
+    for server, sharding in (
+            (Server(cfg, mesh=mesh, max_len=max_len),
+             jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())),
+            (Server(cfg, max_len=max_len), SingleDeviceSharding(
+                topo.devices[0]))):
+        place = functools.partial(jax.tree.map, lambda a: _spec(
+            sharding, a.shape, a.dtype))
+        params = place(jax.eval_shape(server.api.init,
+                                      jax.random.PRNGKey(0)))
+        cache = place(jax.eval_shape(
+            lambda: server.api.init_cache(B, max_len, server.ctx)))
+        yield server, sharding, params, cache
+
+
+def test_hybrid_decode_on_a_mesh_updates_states_in_jnp(topo, monkeypatch):
+    """On a mesh the decode's state update is the jnp step, which XLA
+    partitions; the kernel, which it cannot, is the one-chip path."""
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    for server, sharding, params, cache in _hybrid_on_and_off_a_mesh(topo):
+        text = server._decode.lower(params, cache, _spec(
+            sharding, (4, 1), jnp.int32)).as_text()
+        assert ("tpu_custom_call" in text) == (server.mesh is None)
+
+
+def test_hybrid_prefill_on_a_mesh_runs_the_jnp_ssd(topo, monkeypatch):
+    """The prefill's SSD chooses as the decode's state update does: the
+    kernel on one chip, the jnp scan on a mesh."""
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    for server, sharding, params, _ in _hybrid_on_and_off_a_mesh(topo):
+        text = server._prefill.lower(params, {"tokens": _spec(
+            sharding, (4, 256), jnp.int32)}).as_text()
+        assert ("tpu_custom_call" in text) == (server.mesh is None)
 
 
 def test_block_digest_4mib_panels(one_chip):
