@@ -1,10 +1,11 @@
 """Jitted public wrappers for the Pallas kernels — the one backend seam.
 
-Model code calls these (attention through ``ShardCtx.impl == "pallas"``,
-a prefill's SSD and a decode's state update where :func:`use_kernel`)
-and the integrity layer calls :func:`block_digest`.  :func:`on_tpu` alone decides
-how a kernel runs: compiled by Mosaic on a TPU backend, in interpret mode
-(kernel bodies run as Python over numpy, for correctness) on any other.
+Model code calls a prefill's SSD and a decode's state update where
+:func:`use_kernel` says so (the flash and decode-attention kernels have no
+model caller yet), and the integrity layer calls :func:`block_digest`.
+:func:`on_tpu` alone decides how a kernel runs: compiled by Mosaic on a
+TPU backend, in interpret mode (kernel bodies run as Python over numpy,
+for correctness) on any other.
 Layout conversions between the model's (B, S, H, hd) convention and the
 kernels' (B, H, S, hd) happen here.
 """

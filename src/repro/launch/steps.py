@@ -37,10 +37,10 @@ class StepBundle:
     prefill_step: Optional[Any] = None
 
 
-def make_ctx(api: ModelApi, mesh: Optional[Mesh], plan: CodesignPlan,
-             impl: str = "ref") -> ShardCtx:
+def make_ctx(api: ModelApi, mesh: Optional[Mesh], plan: CodesignPlan
+             ) -> ShardCtx:
     axes = batch_axes_of(mesh) if mesh is not None else ("data",)
-    return ShardCtx(mesh=mesh, batch_axes=axes, model_axis="model", impl=impl,
+    return ShardCtx(mesh=mesh, batch_axes=axes, model_axis="model",
                     seq_parallel=plan.seq_parallel)
 
 
@@ -50,14 +50,14 @@ def abstract_params(api: ModelApi) -> Any:
 
 def make_train_step(api: ModelApi, mesh: Mesh, plan: CodesignPlan,
                     *, lr_peak: float = 3e-4, warmup: int = 100,
-                    total_steps: int = 10000, impl: str = "ref"):
+                    total_steps: int = 10000):
     """Returns (jitted train_step, param_shardings, state_shardings, ctx).
 
     train_step(params, opt_state, batch) -> (params', opt_state', metrics)
     — full forward+backward+AdamW update (what the dry-run compiles).
     """
     cfg = api.cfg
-    ctx = make_ctx(api, mesh, plan, impl)
+    ctx = make_ctx(api, mesh, plan)
     fsdp = plan.sharding in ("fsdp", "fsdp_tp")
 
     p_abs = abstract_params(api)
@@ -128,14 +128,14 @@ def _batch_shardings(api: ModelApi, mesh: Mesh) -> Any:
 
 
 def make_serve_step(api: ModelApi, mesh: Mesh, plan: CodesignPlan,
-                    shape: ShapeSpec, *, impl: str = "ref"):
+                    shape: ShapeSpec):
     """Returns (jitted serve_step, cache_shardings, ctx).
 
     serve_step(params, cache, tokens) -> (logits, cache') — one decode
     token against a seq_len-deep cache (what decode_* / long_* lower).
     """
     cfg = api.cfg
-    ctx = make_ctx(api, mesh, plan, impl)
+    ctx = make_ctx(api, mesh, plan)
     fsdp = plan.sharding in ("fsdp", "fsdp_tp")
     p_abs = abstract_params(api)
     p_shard = param_shardings(p_abs, cfg, mesh, fsdp=fsdp)
@@ -157,9 +157,9 @@ def make_serve_step(api: ModelApi, mesh: Mesh, plan: CodesignPlan,
 
 
 def make_prefill_step(api: ModelApi, mesh: Mesh, plan: CodesignPlan,
-                      shape: ShapeSpec, *, impl: str = "ref"):
+                      shape: ShapeSpec):
     """prefill_step(params, batch) -> (last logits, populated cache)."""
-    ctx = make_ctx(api, mesh, plan, impl)
+    ctx = make_ctx(api, mesh, plan)
     fsdp = plan.sharding in ("fsdp", "fsdp_tp")
     p_abs = abstract_params(api)
     p_shard = param_shardings(p_abs, api.cfg, mesh, fsdp=fsdp)
@@ -203,13 +203,11 @@ def cache_shardings(cache_abs: Any, mesh: Mesh) -> Any:
             b_ax = axes if (B % dp == 0 and B >= dp) else None
             c_ax = "model" if C % m == 0 else None
             return NamedSharding(mesh, P(None, b_ax, None, c_ax))
-        if nd == 5 or nd == 4:
-            pass
         if nd >= 3:          # (L, B, H, P, N) ssm state and friends
             B = v.shape[1]
             b_ax = axes if (B % dp == 0 and B >= dp) else None
             spec = [None, b_ax] + [None] * (nd - 2)
-            if nd >= 3 and v.shape[2] % m == 0:
+            if v.shape[2] % m == 0:
                 spec[2] = "model"
             return NamedSharding(mesh, P(*spec))
         return NamedSharding(mesh, P(*([None] * nd)))

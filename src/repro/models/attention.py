@@ -15,14 +15,13 @@ Positions are explicit everywhere: a KV slot with position < 0 is invalid
 ``q_pos - window < k_pos <= q_pos`` (window == 0 means unbounded), which
 makes ring-buffer caches correct without any index shuffling.
 
-``impl="pallas"`` routes the train/prefill path through the Pallas flash
-kernel (kernels/flash_attention.py); ``"ref"`` is the pure-jnp oracle the
-kernel is validated against.
+The models run these jnp paths only.  The Pallas flash and
+decode-attention kernels (kernels/flash_attention.py,
+kernels/decode_attention.py) are validated against them but have no model
+caller yet.
 """
 
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -92,7 +91,6 @@ def attention(
     k_pos: jax.Array,        # (Sk,) or (B, Sk)
     causal: bool = True,
     window: int | jax.Array = 0,
-    impl: str = "ref",
     q_chunk: int | None = None,
     scale: float | None = None,
 ) -> jax.Array:
@@ -103,11 +101,6 @@ def attention(
     0 = never chunk (caller bounds memory another way, e.g. sequence-
     parallel sharding), >0 = explicit chunk length.
     """
-    if impl == "pallas":
-        out = _try_pallas(q, k, v, q_pos=q_pos, k_pos=k_pos, causal=causal,
-                          window=window, scale=scale)
-        if out is not None:
-            return out
     B, Sq, Hq, hd = q.shape
     Sk = k.shape[1]
     if (q_chunk == 0 or q_pos.ndim != 1
@@ -194,20 +187,6 @@ def attention_decode(
             (e_new / denom).astype(q.dtype).reshape(B, Hkv, G), v_new,
             preferred_element_type=jnp.float32)
         return out.astype(q.dtype).reshape(B, 1, Hq, hd)
-
-
-def _try_pallas(q, k, v, *, q_pos, k_pos, causal, window, scale
-                ) -> Optional[jax.Array]:
-    """Route to the Pallas flash kernel when the shape regime fits it
-    (train/prefill: Sq == Sk a multiple of 128, a static window); any
-    other regime takes the reference.  A kernel error propagates."""
-    if q.shape[1] != k.shape[1] or q.shape[1] % 128:
-        return None
-    if isinstance(window, jax.Array):
-        return None                  # per-layer window traced through scan
-    from repro.kernels import ops as kops
-    return kops.flash_attention(q, k, v, causal=causal, window=int(window),
-                                scale=scale)
 
 
 # ---------------------------------------------------------------------------

@@ -16,9 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import ffn as ffn_lib
 from .attention import attention
-from .common import apply_rope, dense_init, rms_norm
+from .common import apply_rope, dense_init
 from .config import ModelConfig
 
 
@@ -51,8 +50,6 @@ class ShardCtx:
     mesh: Optional[Mesh] = None
     batch_axes: tuple[str, ...] = ("data",)
     model_axis: str = "model"
-    impl: str = "ref"              # attention/ssd kernel impl: ref | pallas
-    moe_impl: str = "auto"         # auto | ep | tp | ref
     seq_parallel: bool = False     # Megatron-SP: layer-boundary activations
     #                                (and remat residuals) shard their seq
     #                                dim over the model axis
@@ -117,13 +114,6 @@ class ShardCtx:
         return self._constrain(x, kv_cache_spec(
             x.shape, dp, self._model_size(), self.batch_axes,
             self.model_axis))
-
-    def choose_moe(self, cfg: ModelConfig) -> str:
-        if self.moe_impl != "auto":
-            return self.moe_impl
-        if self.mesh is None:
-            return "ref"
-        return ffn_lib.choose_moe_impl(cfg, self.mesh, self.model_axis)
 
 
 # ---------------------------------------------------------------------------
@@ -243,7 +233,7 @@ def self_attention_block(
     # GQA sharding repair: when Hq shards over the model axis but Hkv does
     # not (kv=8 on a 16-wide axis), the (Hkv, G) grouping reshape breaks
     # the head sharding of the score tensor and GSPMD falls back to full
-    # rematerialization (measured: ~1 TiB/dev score all-gathers, §Perf M2).
+    # rematerialization (measured: ~1 TiB/dev score all-gathers).
     # Materializing the KV head repeat costs ~MBs and keeps every
     # attention tensor cleanly model-sharded.  (The Pallas kernel does GQA
     # without the repeat on real TPU — this is the GSPMD-graph trade.)
@@ -267,57 +257,13 @@ def self_attention_block(
     # score workspace is already bounded, and the chunk scan's extra
     # sharding transitions trigger involuntary full rematerialization in
     # GSPMD (measured: 4.2 TiB/dev of score all-gathers on
-    # mistral-large train_4k — EXPERIMENTS.md §Perf iteration M1)
+    # mistral-large train_4k)
     if q_chunk is None and (ctx.heads_shardable(cfg.n_heads)
                             or ctx.seq_parallel_attn(cfg.n_heads, S)):
         q_chunk = 0
     out = attention(q, k_all, v_all, q_pos=q_pos, k_pos=k_pos,
-                    causal=causal, window=window, impl=ctx.impl,
-                    q_chunk=q_chunk, scale=cfg.attention_multiplier)
+                    causal=causal, window=window, q_chunk=q_chunk,
+                    scale=cfg.attention_multiplier)
     out = out.reshape(B, S, cfg.q_dim)
     return jnp.einsum("bsq,qd->bsd", out, p["wo"]), k, v
 
-
-def dense_layer_apply(
-    x: jax.Array, p: dict, cfg: ModelConfig, ctx: ShardCtx, *,
-    positions: jax.Array, window: int | jax.Array = 0, causal: bool = True,
-) -> jax.Array:
-    """Full pre-norm transformer layer (train/prefill path, no cache)."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    attn_out, _, _ = self_attention_block(
-        h, p["attn"], cfg, ctx, q_pos=positions, k_pos=positions,
-        causal=causal, window=window)
-    x = ctx.shard_act(x + attn_out)
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    mlp_out = ffn_lib.swiglu(h2, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                             p["mlp"]["w_down"])
-    return ctx.shard_act(x + mlp_out)
-
-
-def moe_layer_apply(
-    x: jax.Array, p: dict, cfg: ModelConfig, ctx: ShardCtx, *,
-    positions: jax.Array, window: int | jax.Array = 0, causal: bool = True,
-) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """MoE transformer layer; returns (x, lb_loss, z_loss)."""
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    attn_out, _, _ = self_attention_block(
-        h, p["attn"], cfg, ctx, q_pos=positions, k_pos=positions,
-        causal=causal, window=window)
-    x = ctx.shard_act(x + attn_out)
-    h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-    moe = p["moe"]
-    impl = ctx.choose_moe(cfg)
-    if impl == "ep":
-        y, lb, z = ffn_lib.moe_ep(h2, moe["router"], moe["w_gate"],
-                                  moe["w_up"], moe["w_down"], cfg=cfg,
-                                  mesh=ctx.mesh, batch_axes=ctx.batch_axes,
-                                  model_axis=ctx.model_axis)
-    elif impl == "tp":
-        y, lb, z = ffn_lib.moe_tp(h2, moe["router"], moe["w_gate"],
-                                  moe["w_up"], moe["w_down"], cfg=cfg,
-                                  mesh=ctx.mesh, batch_axes=ctx.batch_axes,
-                                  model_axis=ctx.model_axis)
-    else:
-        y, lb, z = ffn_lib.moe_ref(h2, moe["router"], moe["w_gate"],
-                                   moe["w_up"], moe["w_down"], cfg=cfg)
-    return ctx.shard_act(x + y), lb, z

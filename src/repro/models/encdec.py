@@ -82,7 +82,7 @@ def encode(params: dict, cfg: ModelConfig, frames: jax.Array, ctx: ShardCtx
         hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
         q, k, v = _proj_qkv(hn, lp["attn"], cfg, ctx, positions)
         out = attention(q, k, v, q_pos=positions, k_pos=positions,
-                        causal=False, impl=ctx.impl)
+                        causal=False)
         B = h.shape[0]
         h = ctx.shard_act(
             h + jnp.einsum("bsq,qd->bsd", out.reshape(B, S, cfg.q_dim),
@@ -109,7 +109,7 @@ def _decoder_stack(params, cfg, x, enc_out, ctx):
         hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
         q, k, v = _proj_qkv(hn, lp["attn"], cfg, ctx, positions)
         out = attention(q, k, v, q_pos=positions, k_pos=positions,
-                        causal=True, impl=ctx.impl)
+                        causal=True)
         h = ctx.shard_act(
             h + jnp.einsum("bsq,qd->bsd", out.reshape(B, S, cfg.q_dim),
                            lp["attn"]["wo"]))
@@ -123,7 +123,7 @@ def _decoder_stack(params, cfg, x, enc_out, ctx):
             B, S_enc, cfg.n_kv_heads, cfg.hd)
         out = attention(ctx.shard_heads(qc), ctx.shard_heads(kc),
                         ctx.shard_heads(vc), q_pos=positions,
-                        k_pos=enc_positions, causal=False, impl=ctx.impl)
+                        k_pos=enc_positions, causal=False)
         h = ctx.shard_act(
             h + jnp.einsum("bsq,qd->bsd", out.reshape(B, S, cfg.q_dim),
                            lp["cross"]["wo"]))

@@ -22,6 +22,12 @@ Heterogeneity is handled without breaking the scan:
   position's write touches every tile, and reads it per head more slowly
   than the flat rows even where hd is 128 (PERF.md, PR 14).
 
+Every attention layer, of any family, runs one body per phase:
+``_attn_layer`` over whole sequences (training and prefill) and
+``_decode_layer`` for one token.  Both end in ``_ffn_residual``, the FFN
+half that Mamba layers with an MLP share, and the one place that
+chooses how a MoE runs.
+
 Remat policy (cfg.remat): 'full' checkpoints each layer body (only layer
 boundaries persist for backward), 'dots' saves matmul outputs, 'none'
 stores everything.
@@ -39,8 +45,8 @@ from . import ffn as ffn_lib
 from . import ssm as ssm_lib
 from .attention import (attention_decode, cache_positions_full,
                         cache_positions_ring)
-from .blocks import (ShardCtx, dense_layer_apply, init_mlp_params,
-                     moe_layer_apply, self_attention_block, stack_layers)
+from .blocks import (ShardCtx, init_mlp_params, self_attention_block,
+                     stack_layers)
 from .common import (apply_rope, cross_entropy_loss, dense_init, embed_init,
                      rms_norm)
 from .config import ModelConfig
@@ -131,12 +137,32 @@ def _add(x, y, cfg):
     return x + y
 
 
-def _mlp_residual(x, lp, cfg, ctx):
-    """x + MLP(RMSNorm(x)): the second half of a layer."""
+def _ffn_residual(x, lp, cfg, ctx):
+    """x + FFN(RMSNorm(x)), the second half of every layer in every phase:
+    the layer's MoE where it has one, else its SwiGLU MLP.  The MoE runs
+    expert parallel where the experts divide the mesh's model axis,
+    tensor parallel inside the experts where they do not, and as the
+    dense oracle with no mesh.  Returns (x, lb, z): the MoE's load-balance
+    and router z losses, zeros for an MLP."""
     h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-    y = ffn_lib.swiglu(h, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
-                       lp["mlp"]["w_down"])
-    return ctx.shard_act(_add(x, y, cfg))
+    if "moe" in lp:
+        m = lp["moe"]
+        w = (m["router"], m["w_gate"], m["w_up"], m["w_down"])
+        if ctx.mesh is None:
+            y, lb, z = ffn_lib.moe_ref(h, *w, cfg=cfg)
+        elif ffn_lib.choose_moe_impl(cfg, ctx.mesh, ctx.model_axis) == "ep":
+            y, lb, z = ffn_lib.moe_ep(h, *w, cfg=cfg, mesh=ctx.mesh,
+                                      batch_axes=ctx.batch_axes,
+                                      model_axis=ctx.model_axis)
+        else:
+            y, lb, z = ffn_lib.moe_tp(h, *w, cfg=cfg, mesh=ctx.mesh,
+                                      batch_axes=ctx.batch_axes,
+                                      model_axis=ctx.model_axis)
+    else:
+        y = ffn_lib.swiglu(h, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
+                           lp["mlp"]["w_down"])
+        lb = z = jnp.zeros((), jnp.float32)
+    return ctx.shard_act(_add(x, y, cfg)), lb, z
 
 
 def _mamba_layer_apply(x, lp, cfg, ctx, return_state: bool = False):
@@ -149,32 +175,8 @@ def _mamba_layer_apply(x, lp, cfg, ctx, return_state: bool = False):
     y, st = out if return_state else (out, None)
     x = ctx.shard_act(_add(x, y, cfg))
     if "mlp" in lp:
-        x = _mlp_residual(x, lp, cfg, ctx)
+        x = _ffn_residual(x, lp, cfg, ctx)[0]
     return (x, st) if return_state else x
-
-
-def _scan_stack(x, layers, cfg, ctx, positions, windows, body_kind,
-                n_layers=None):
-    """Scan a homogeneous stack.  Returns (x, lb_sum, z_sum)."""
-
-    def dense_body(carry, xs):
-        h, lb, z = carry
-        lp, w = xs
-        h = dense_layer_apply(h, lp, cfg, ctx, positions=positions, window=w)
-        return (h, lb, z), None
-
-    def moe_body(carry, xs):
-        h, lb, z = carry
-        lp, w = xs
-        h, lbi, zi = moe_layer_apply(h, lp, cfg, ctx, positions=positions,
-                                     window=w)
-        return (h, lb + lbi, z + zi), None
-
-    body = {"attn": dense_body, "moe": moe_body}[body_kind]
-    body = _remat(body, cfg.remat)
-    zero = jnp.zeros((), jnp.float32)
-    (x, lb, z), _ = jax.lax.scan(body, (x, zero, zero), (layers, windows))
-    return x, lb, z
 
 
 def forward_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
@@ -186,12 +188,19 @@ def forward_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
     positions = jnp.arange(S, dtype=jnp.int32)
     windows = jnp.asarray(cfg.layer_windows(), jnp.int32)
 
-    if cfg.family in ("dense", "vlm"):
-        x, lb, z = _scan_stack(x, params["layers"], cfg, ctx, positions,
-                               windows, "attn")
-    elif cfg.family == "moe":
-        x, lb, z = _scan_stack(x, params["layers"], cfg, ctx, positions,
-                               windows, "moe")
+    if cfg.family in ("dense", "vlm", "moe"):
+        def body(carry, xs):
+            h, lb, z = carry
+            lp, w = xs
+            h, _, _, lbi, zi = _attn_layer(h, lp, cfg, ctx, positions, w)
+            if "moe" in lp:     # an MLP's zeros would only add ops
+                lb, z = lb + lbi, z + zi
+            return (h, lb, z), None
+
+        zero = jnp.zeros((), jnp.float32)
+        (x, lb, z), _ = jax.lax.scan(_remat(body, cfg.remat),
+                                     (x, zero, zero),
+                                     (params["layers"], windows))
     elif cfg.family in ("ssm", "hybrid"):
         x = _hybrid_forward(params, cfg, x, ctx, positions)
         lb = z = jnp.zeros((), jnp.float32)
@@ -216,15 +225,18 @@ def _scan_layers(body, carry, layers: dict, lo: int, hi: int):
     return jax.lax.scan(step, carry, jnp.arange(lo, hi, dtype=jnp.int32))[0]
 
 
-def _attn_site(x, lp, cfg, ctx, positions, q_chunk=None):
-    """A hybrid's attention layer over a whole sequence: attention, then
-    its MLP.  Returns (x, k, v), the keys and values (B, S, Hkv, hd)."""
+def _attn_layer(x, lp, cfg, ctx, positions, window, q_chunk=None):
+    """An attention layer over a whole sequence, in training and prefill:
+    causal attention within ``window``, then the FFN half.  Returns
+    (x, k, v, lb, z): the keys and values (B, S, Hkv, hd) and the FFN's
+    losses (``_ffn_residual``)."""
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     out, k, v = self_attention_block(
         h, lp["attn"], cfg, ctx, q_pos=positions, k_pos=positions,
-        causal=True, window=cfg.window, q_chunk=q_chunk)
+        causal=True, window=window, q_chunk=q_chunk)
     x = ctx.shard_act(_add(x, out, cfg))
-    return _mlp_residual(x, lp, cfg, ctx), k, v
+    x, lb, z = _ffn_residual(x, lp, cfg, ctx)
+    return x, k, v, lb, z
 
 
 def _hybrid_forward(params, cfg, x, ctx, positions):
@@ -236,8 +248,8 @@ def _hybrid_forward(params, cfg, x, ctx, positions):
         if kind == "mamba":
             x = _scan_layers(body, x, params["layers"], a, b)
         else:
-            x, _, _ = _attn_site(x, _layer(params["attn_layers"], a), cfg,
-                                 ctx, positions)
+            x = _attn_layer(x, _layer(params["attn_layers"], a), cfg, ctx,
+                            positions, cfg.window)[0]
     return x
 
 
@@ -274,57 +286,13 @@ def prefill_lm(params: dict, cfg: ModelConfig, tokens: jax.Array,
     s_cache = _attn_cache_len(cfg, max_len)
 
     if cfg.family in ("dense", "vlm", "moe"):
-        is_moe = cfg.family == "moe"
-
-        def body(carry, xs):
-            h = carry
+        def body(h, xs):
             lp, w = xs
-            hn = rms_norm(h, lp["ln1"], cfg.norm_eps)
-            attn_out, k_new, v_new = self_attention_block(
-                hn, lp["attn"], cfg, ctx, q_pos=positions, k_pos=positions,
-                causal=True, window=w)
-            h = ctx.shard_act(h + attn_out)
-            h2 = rms_norm(h, lp["ln2"], cfg.norm_eps)
-            if is_moe:
-                moe_p = lp["moe"]
-                impl = ctx.choose_moe(cfg)
-                if impl == "ep":
-                    y, _, _ = ffn_lib.moe_ep(h2, moe_p["router"],
-                                             moe_p["w_gate"], moe_p["w_up"],
-                                             moe_p["w_down"], cfg=cfg,
-                                             mesh=ctx.mesh,
-                                             batch_axes=ctx.batch_axes,
-                                             model_axis=ctx.model_axis)
-                elif impl == "tp":
-                    y, _, _ = ffn_lib.moe_tp(h2, moe_p["router"],
-                                             moe_p["w_gate"], moe_p["w_up"],
-                                             moe_p["w_down"], cfg=cfg,
-                                             mesh=ctx.mesh,
-                                             batch_axes=ctx.batch_axes,
-                                             model_axis=ctx.model_axis)
-                else:
-                    y, _, _ = ffn_lib.moe_ref(h2, moe_p["router"],
-                                              moe_p["w_gate"], moe_p["w_up"],
-                                              moe_p["w_down"], cfg=cfg)
-            else:
-                y = ffn_lib.swiglu(h2, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
-                                   lp["mlp"]["w_down"])
-            h = ctx.shard_act(h + y)
-            k_new = k_new.reshape(B, S, cfg.kv_dim)
-            v_new = v_new.reshape(B, S, cfg.kv_dim)
-            if ring:
-                k_c = _ring_pack(k_new, s_cache)
-                v_c = _ring_pack(v_new, s_cache)
-            else:
-                pad = [(0, 0)] * 3
-                pad[1] = (0, max_len - S)
-                k_c = jnp.pad(k_new, pad)
-                v_c = jnp.pad(v_new, pad)
-            return h, (k_c.astype(jnp.bfloat16), v_c.astype(jnp.bfloat16))
+            h, k, v, _, _ = _attn_layer(h, lp, cfg, ctx, positions, w)
+            return h, (_pack_kv(k, ring, s_cache), _pack_kv(v, ring, s_cache))
 
-        body = _remat(body, cfg.remat)
-        x, (k_all, v_all) = jax.lax.scan(body, x, (params["layers"], windows))
-        cache["k"], cache["v"] = k_all, v_all
+        x, (cache["k"], cache["v"]) = jax.lax.scan(
+            _remat(body, cfg.remat), x, (params["layers"], windows))
 
     elif cfg.family in ("ssm", "hybrid"):
         x, cache = _hybrid_prefill(params, cfg, x, ctx, positions, cache,
@@ -433,8 +401,9 @@ def _hybrid_prefill_rows(params, cfg, x, ctx, positions, cache, s_cache):
         if kind == "mamba":
             x, states = _scan_layers(body, (x, states), params["layers"], a, b)
         else:
-            x, k, v = _attn_site(x, _layer(params["attn_layers"], a), cfg,
-                                 ctx, positions, q_chunk)
+            x, k, v, _, _ = _attn_layer(
+                x, _layer(params["attn_layers"], a), cfg, ctx, positions,
+                cfg.window, q_chunk)
             k_sites.append(_pack_kv(k, ring, s_cache))
             v_sites.append(_pack_kv(v, ring, s_cache))
     cache["mamba"] = states
@@ -509,12 +478,13 @@ def init_lm_cache(cfg: ModelConfig, batch: int, max_len: int,
     return cache
 
 
-def _decode_attn_block(x, lp, cfg, ctx, k_cache, v_cache, pos, window,
-                       ring: bool):
+def _decode_layer(x, lp, cfg, ctx, k_cache, v_cache, pos, window,
+                  ring: bool):
     """One decode step through one attention layer, reading its cache
-    (B, S, Hkv * hd), which does not hold step ``pos`` yet.  Returns
-    (x_out, k, v): the step's key and value, (B, 1, Hkv * hd) in the
-    cache's dtype, for the caller to store (``_store_step``)."""
+    (B, S, Hkv * hd), which does not hold step ``pos`` yet, then the FFN
+    half.  Returns (x_out, k, v): the step's key and value,
+    (B, 1, Hkv * hd) in the cache's dtype, for the caller to store
+    (``_store_step``)."""
     h = rms_norm(x, lp["ln1"], cfg.norm_eps)
     B = x.shape[0]
     q_pos = jnp.broadcast_to(pos, (1,)).astype(jnp.int32)
@@ -537,6 +507,7 @@ def _decode_attn_block(x, lp, cfg, ctx, k_cache, v_cache, pos, window,
                            scale=cfg.attention_multiplier)
     out = out.reshape(B, 1, cfg.q_dim)
     x = _add(x, jnp.einsum("bsq,qd->bsd", out, lp["attn"]["wo"]), cfg)
+    x = _ffn_residual(x, lp, cfg, ctx)[0]
     return (x, k.reshape(B, 1, cfg.kv_dim).astype(k_cache.dtype),
             v.reshape(B, 1, cfg.kv_dim).astype(v_cache.dtype))
 
@@ -562,36 +533,11 @@ def lm_decode_step(params: dict, cfg: ModelConfig, cache: dict,
     windows = jnp.asarray(cfg.layer_windows(), jnp.int32)
 
     if cfg.family in ("dense", "vlm", "moe"):
-        is_moe = cfg.family == "moe"
-
         def body(h, xs):
             lp, k_l, v_l, w = xs
-            h, k_t, v_t = _decode_attn_block(h, lp, cfg, ctx, k_l, v_l, pos,
-                                             w, ring)
-            h2 = rms_norm(h, lp["ln2"], cfg.norm_eps)
-            if is_moe:
-                moe = lp["moe"]
-                impl = ctx.choose_moe(cfg)
-                if impl == "ep":
-                    y, _, _ = ffn_lib.moe_ep(h2, moe["router"], moe["w_gate"],
-                                             moe["w_up"], moe["w_down"],
-                                             cfg=cfg, mesh=ctx.mesh,
-                                             batch_axes=ctx.batch_axes,
-                                             model_axis=ctx.model_axis)
-                elif impl == "tp":
-                    y, _, _ = ffn_lib.moe_tp(h2, moe["router"], moe["w_gate"],
-                                             moe["w_up"], moe["w_down"],
-                                             cfg=cfg, mesh=ctx.mesh,
-                                             batch_axes=ctx.batch_axes,
-                                             model_axis=ctx.model_axis)
-                else:
-                    y, _, _ = ffn_lib.moe_ref(h2, moe["router"], moe["w_gate"],
-                                              moe["w_up"], moe["w_down"],
-                                              cfg=cfg)
-            else:
-                y = ffn_lib.swiglu(h2, lp["mlp"]["w_gate"], lp["mlp"]["w_up"],
-                                   lp["mlp"]["w_down"])
-            return h + y, (k_t, v_t)
+            h, k_t, v_t = _decode_layer(h, lp, cfg, ctx, k_l, v_l, pos, w,
+                                        ring)
+            return h, (k_t, v_t)
 
         x, (k_step, v_step) = jax.lax.scan(
             body, x, (params["layers"], cache["k"], cache["v"], windows))
@@ -626,7 +572,7 @@ def _hybrid_decode(params, cfg, cache, x, ctx, pos):
             hn, lp, cfg, states, l, mesh=ctx.mesh)
         h = _add(h, y, cfg)
         if "mlp" in lp:
-            h = _mlp_residual(h, lp, cfg, ctx)
+            h = _ffn_residual(h, lp, cfg, ctx)[0]
         return h, states
 
     states = cache["mamba"]
@@ -635,10 +581,9 @@ def _hybrid_decode(params, cfg, cache, x, ctx, pos):
             x, states = _scan_layers(body, (x, states), params["layers"], a, b)
         else:
             lp = _layer(params["attn_layers"], a)
-            x, k_t, v_t = _decode_attn_block(
+            x, k_t, v_t = _decode_layer(
                 x, lp, cfg, ctx, cache["attn_k"][b], cache["attn_v"][b], pos,
                 cfg.window, ring)
-            x = _mlp_residual(x, lp, cfg, ctx)
             k_sites.append(k_t)
             v_sites.append(v_t)
     new_cache["mamba"] = states

@@ -152,6 +152,49 @@ with tempfile.TemporaryDirectory() as d:
     np.testing.assert_array_equal(np.asarray(out["w"]), np.asarray(tree["w"]))
     assert out["w"].sharding == sh2["w"]
 print("MARKER elastic-ok")
+
+# --- 7. MoE prefill + decode steps on the mesh match a mesh-less run -------
+from repro.models.api import ShapeSpec
+
+def moe_serve(n_experts, want_impl):
+    # K/V heads divide the model axis: a prefill whose K/V heads do not
+    # fails on a mesh (ROADMAP D15)
+    cfg = ModelConfig(name="moe-serve", family="moe", n_layers=2, d_model=32,
+                      n_heads=8, n_kv_heads=4, head_dim=8, d_ff=64, vocab=64,
+                      moe=MoEConfig(n_experts=n_experts, top_k=2,
+                                    d_ff_expert=64, capacity_factor=8.0))
+    assert ffn_lib.choose_moe_impl(cfg, mesh) == want_impl
+    api = build(cfg)
+    B, S, G = 4, 8, 4
+    shape = ShapeSpec("moe-serve", S + G, B, "decode")
+    plan = CodesignPlan(sharding="fsdp_tp", microbatches=1, remat="none",
+                        seq_parallel=False)
+    prefill, _ = steps_lib.make_prefill_step(api, mesh, plan, shape)
+    serve, cache_shard, _ = steps_lib.make_serve_step(api, mesh, plan, shape)
+    params = jax.tree.map(lambda a: a.astype(jnp.float32),
+                          api.init(jax.random.PRNGKey(7)))
+    toks = jax.random.randint(jax.random.PRNGKey(8), (B, S + G), 0, cfg.vocab,
+                              jnp.int32)
+    ctx0 = ShardCtx()
+    ref_logits, ref_cache = jax.jit(lambda p, t: api.prefill(
+        p, {"tokens": t}, ctx0, max_len=S + G))(params, toks[:, :S])
+    logits, cache = prefill(params, {"tokens": toks[:, :S],
+                                     "labels": toks[:, :S]})
+    cache = jax.device_put(cache, cache_shard)
+    ref_step = jax.jit(lambda p, c, t: api.decode_step(p, c, t, ctx0))
+    got, want = [logits[:, -1]], [ref_logits[:, -1]]
+    for i in range(S, S + G):
+        logits, cache = serve(params, cache, toks[:, i:i + 1])
+        ref_logits, ref_cache = ref_step(params, ref_cache, toks[:, i:i + 1])
+        got.append(logits[:, -1])
+        want.append(ref_logits[:, -1])
+    np.testing.assert_allclose(np.stack([np.asarray(g) for g in got]),
+                               np.stack([np.asarray(w) for w in want]),
+                               atol=2e-4, rtol=2e-4)
+    print(f"MARKER moe-serve-{want_impl}-ok")
+
+moe_serve(8, "ep")     # 8 experts divide the 4-wide model axis
+moe_serve(6, "tp")     # 6 do not
 print("MARKER all-ok")
 '''
 
@@ -189,6 +232,11 @@ def test_pipeline_parallel_forward(dist_output):
 
 def test_elastic_checkpoint_reshard(dist_output):
     assert "MARKER elastic-ok" in dist_output
+
+
+@pytest.mark.parametrize("impl", ["ep", "tp"])
+def test_moe_prefill_decode_on_mesh_match_meshless(dist_output, impl):
+    assert f"MARKER moe-serve-{impl}-ok" in dist_output
 
 
 def test_all_distribution_checks(dist_output):
